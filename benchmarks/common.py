@@ -16,27 +16,22 @@ from typing import Callable, Optional
 import numpy as np
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache (ROADMAP: shrink/merge dispatches are
-# compile-dominated on CPU hosts; cached executables amortize across runs)
+# persistent compilation cache (cold compiles count against every run; the
+# cached executables amortize them across processes and runs)
 #
-# OPT-IN ONLY (`REPRO_COMPILATION_CACHE=1`). On this container's
-# jaxlib 0.4.36 / CPU, executables DESERIALIZED from the persistent cache
-# mishandle buffer donation: donated pass-through outputs (e.g. the engine's
-# untouched `lh_dir` plane) nondeterministically come back corrupted, and
-# large cached SMO dispatches can crash outright — a use-after-free of the
-# donated input buffer. Fresh-compiled executables are unaffected, so only
-# the SECOND-and-later processes ever see it, which is exactly what made it
-# look like test flakiness (tests/test_batch_parallel caught it: `lh_dir`
-# diverged between the scan and segment engines on a delete that touches
-# neither). Until the deployment jaxlib handles donation in deserialized
-# executables, the cache stays off by default; the plumbing + hit/miss
-# accounting below is ready to flip on.
+# On by default. An older jaxlib (0.4.36, CPU) mishandled buffer donation in
+# executables deserialized from this cache, so it used to be opt-in. On the
+# installed JAX 0.9 the differential that caught it (tests/
+# test_batch_parallel.py, scan vs segment engines) passes on a cold and on
+# two warm runs of one cache directory.
 # ---------------------------------------------------------------------------
 
 _CACHE_STATS = {"hits": 0, "misses": 0}
 _cache_enabled = False
 
-CACHE_OPT_IN_ENV = "REPRO_COMPILATION_CACHE"
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
 
 
 def _cache_listener(event: str, **kwargs):
@@ -46,31 +41,27 @@ def _cache_listener(event: str, **kwargs):
         _CACHE_STATS["misses"] += 1
 
 
-def enable_compilation_cache(path: str | None = None,
-                             force: bool = False) -> str | None:
-    """Idempotent: point JAX's persistent compilation cache at a repo-local
-    directory (``.jax_cache/``, gitignored) and start counting hits/misses.
-    Call before the first jit dispatch; benches record ``cache_stats()`` in
-    their JSON artifacts so a compile-dominated run is visible.
+def enable_compilation_cache() -> str:
+    """Idempotent: turn JAX's persistent compilation cache on and start
+    counting hits/misses. Call before the first jit dispatch; benches record
+    ``cache_stats()`` in their JSON artifacts so a compile-dominated run is
+    visible.
 
-    No-op (returns None) unless ``REPRO_COMPILATION_CACHE=1`` or
-    ``force=True`` — see the donation-corruption note above."""
+    The directory is ``$JAX_COMPILATION_CACHE_DIR`` when that is set (JAX
+    reads it itself, and no other directory is set here), else the
+    checkout's ``.jax_cache/`` (gitignored): a fixed path, since the path is
+    part of what a cache entry is found by."""
     global _cache_enabled
     import jax
-    if _cache_enabled:
-        return jax.config.jax_compilation_cache_dir
-    if not force and os.environ.get(CACHE_OPT_IN_ENV) != "1":
-        return None
-    if path is None:
-        path = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                            ".jax_cache"))
-    jax.config.update("jax_compilation_cache_dir", path)
-    # tiny kernels dominate this repo: cache everything, not just slow builds
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.monitoring.register_event_listener(_cache_listener)
-    _cache_enabled = True
-    return path
+    if not _cache_enabled:
+        if not os.environ.get(CACHE_DIR_ENV):
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+        # tiny kernels dominate this repo: cache everything, not just slow builds
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.monitoring.register_event_listener(_cache_listener)
+        _cache_enabled = True
+    return jax.config.jax_compilation_cache_dir
 
 
 def cache_stats() -> dict:

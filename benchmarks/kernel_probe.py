@@ -26,7 +26,8 @@ def run():
     # lanes overflowed routing capacity and are untouched by design)
     f_eng, v_eng = engine.search_batch(cfg, "eh", t.state, hi, lo,
                                        batching="vmap")
-    f_krn, v_krn, keep = ops.probe_routed(cfg, t.state, hi, lo, capacity=512)
+    f_krn, v_krn, keep = ops.probe_routed(cfg, t.state, hi, lo, capacity=512,
+                                          interpret=True)
     keep = np.asarray(keep)
     assert (np.asarray(f_eng)[keep] == np.asarray(f_krn)[keep]).all()
     hit = np.asarray(f_eng) & keep
@@ -36,6 +37,7 @@ def run():
     s_eng = time_op(lambda: jax.block_until_ready(
         engine.search_batch(cfg, "eh", t.state, hi, lo, batching="vmap")))
     s_krn = time_op(lambda: jax.block_until_ready(
-        ops.probe_routed(cfg, t.state, hi, lo, capacity=512)))
+        ops.probe_routed(cfg, t.state, hi, lo, capacity=512,
+                         interpret=True)))
     return [ops_row("kernel/engine_search(vmap)", s_eng, 1024),
             ops_row("kernel/pallas_probe_routed(interpret)", s_krn, 1024)]

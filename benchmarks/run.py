@@ -118,6 +118,8 @@ def main() -> None:
             print(f"{tag},{modname},{artifact or '-'},{status}", flush=True)
         return
 
+    from .common import enable_compilation_cache
+    enable_compilation_cache()
     print("name,us_per_call,derived")
     failures = []
     for tag, modname in MODULES:

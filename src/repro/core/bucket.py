@@ -28,6 +28,39 @@ from .layout import DashConfig, DashState, U32
 I32 = jnp.int32
 
 
+def _slot_mask(plane, b, slot):
+    """(1, rows, cols) bool: the one element ``[0, b, slot]`` of a
+    single-segment plane (none when ``b`` or ``slot`` is out of range)."""
+    rows = jnp.arange(plane.shape[1], dtype=I32)[:, None]
+    cols = jnp.arange(plane.shape[2], dtype=I32)[None, :]
+    return ((rows == b) & (cols == slot))[None]
+
+
+def set_slot(plane, seg, b, slot, x):
+    """``plane.at[seg, b, slot].set(x)`` for an (S, rows, cols) plane.
+
+    On a single-segment view (S == 1: the body the segment-parallel engines
+    vmap over every segment) the write is a masked select instead of an
+    element scatter. Vmapped over 2**14 segments, XLA:TPU (v5e) put the
+    three-index scatter's updates in the wrong elements, while the same
+    program on the CPU matched bit for bit; the select has no index to get
+    wrong. An out-of-range ``b`` or ``slot`` writes nothing in both forms.
+    """
+    if plane.shape[0] != 1:
+        return plane.at[seg, b, slot].set(x)
+    return jnp.where(_slot_mask(plane, b, slot),
+                     jnp.asarray(x).astype(plane.dtype), plane)
+
+
+def get_slot(plane, seg, b, slot):
+    """``plane[seg, b, slot]``; a masked reduction on a single-segment view
+    (the read twin of :func:`set_slot`)."""
+    if plane.shape[0] != 1:
+        return plane[seg, b, slot]
+    return jnp.max(jnp.where(_slot_mask(plane, b, slot), plane,
+                             jnp.zeros((), plane.dtype)))
+
+
 def slot_fp_matches(cfg: DashConfig, state: DashState, seg, b, fpv):
     """(SLOTS,) bool — allocated slots whose fingerprint matches.
 
@@ -65,7 +98,7 @@ def bucket_probe(cfg: DashConfig, state: DashState, seg, b, fpv, q_hi, q_lo, q_w
     eq = cand & keys_equal(cfg, state, seg, b, q_hi, q_lo, q_words)
     found = jnp.any(eq)
     slot = jnp.argmax(eq).astype(I32)
-    return found, slot, state.val[seg, b, slot]
+    return found, slot, get_slot(state.val, seg, b, slot)
 
 
 def first_free_slot(cfg: DashConfig, state: DashState, seg, b):
@@ -93,10 +126,10 @@ def bucket_write(cfg: DashConfig, state: DashState, seg, b, slot,
     (3) one atomic store of alloc|membership|count, (4) version bump.
     """
     state = state._replace(
-        key_hi=state.key_hi.at[seg, b, slot].set(k_hi),
-        key_lo=state.key_lo.at[seg, b, slot].set(k_lo),
-        val=state.val.at[seg, b, slot].set(v),
-        fp=state.fp.at[seg, b, slot].set(fpv),
+        key_hi=set_slot(state.key_hi, seg, b, slot, k_hi),
+        key_lo=set_slot(state.key_lo, seg, b, slot, k_lo),
+        val=set_slot(state.val, seg, b, slot, v),
+        fp=set_slot(state.fp, seg, b, slot, fpv),
     )
     meta = state.meta[seg, b]
     alloc = layout.meta_alloc(meta) | (U32(1) << slot.astype(U32))
@@ -133,8 +166,8 @@ def find_movable_slot(cfg: DashConfig, state: DashState, seg, b, want_member_set
 
 
 def read_slot(state: DashState, seg, b, slot):
-    return (state.key_hi[seg, b, slot], state.key_lo[seg, b, slot],
-            state.val[seg, b, slot], state.fp[seg, b, slot])
+    return tuple(get_slot(p, seg, b, slot) for p in
+                 (state.key_hi, state.key_lo, state.val, state.fp))
 
 
 # ---- overflow (stash) metadata on the home bucket --------------------------
@@ -165,7 +198,7 @@ def ofp_try_set(cfg: DashConfig, state: DashState, seg, b, fpv, stash_idx, membe
     om_out = jnp.where(ok, om2, om)
     st = state._replace(
         ometa=state.ometa.at[seg, b].set(om_out),
-        ofp=jnp.where(ok, state.ofp.at[seg, b, slot].set(fpv), state.ofp),
+        ofp=jnp.where(ok, set_slot(state.ofp, seg, b, slot, fpv), state.ofp),
         version=jnp.where(ok, state.version.at[seg, b].add(U32(2)),
                           state.version),
     )

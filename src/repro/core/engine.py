@@ -700,7 +700,8 @@ def update_in_segment(cfg: DashConfig, state: DashState, seg, b, h2,
         f, slot, _ = bk.bucket_probe(cfg, state, seg, bw, fpv, q_hi, q_lo, q_words)
         do = f & (status == NOT_FOUND)
         state = state._replace(
-            val=jnp.where(do, state.val.at[seg, bw, slot].set(v), state.val),
+            val=jnp.where(do, bk.set_slot(state.val, seg, bw, slot, v),
+                          state.val),
             version=jnp.where(do, state.version.at[seg, bw].add(U32(2)),
                               state.version))
         status = jnp.where(do, I32(INSERTED), status)
@@ -709,7 +710,8 @@ def update_in_segment(cfg: DashConfig, state: DashState, seg, b, h2,
         f, slot, _ = bk.bucket_probe(cfg, state, seg, sb, fpv, q_hi, q_lo, q_words)
         do = f & (s < state.stash_active[seg]) & (status == NOT_FOUND)
         state = state._replace(
-            val=jnp.where(do, state.val.at[seg, sb, slot].set(v), state.val),
+            val=jnp.where(do, bk.set_slot(state.val, seg, sb, slot, v),
+                          state.val),
             version=jnp.where(do, state.version.at[seg, sb].add(U32(2)),
                               state.version))
         status = jnp.where(do, I32(INSERTED), status)

@@ -15,8 +15,6 @@ from typing import Optional
 import numpy as np
 import jax.numpy as jnp
 
-from repro.kernels import ops
-
 from . import dash_eh, dash_lh, engine, hashing, layout, recovery, smo
 from .epoch import DirtyHint
 from .layout import (EXISTS, INSERTED, NEED_SPLIT, NOT_FOUND, DashConfig,
@@ -212,9 +210,10 @@ class DashTable:
         exploit, so it stays on the scan engine until splits spread the
         directory. ``fused_ok=False`` (delete/update, which have no fused
         engine) skips the latency path."""
+        from repro.kernels import fused    # local: kernels import core
         capacity = self._lane_quantum(self._max_per_segment(seg))
         if (fused_ok and n_total <= self.fused_threshold
-                and ops.fused_insert_eligible(self.cfg)):
+                and fused.fused_insert_eligible(self.cfg)):
             return "fused", capacity
         if capacity * 4 <= self._pow2(n_total):
             return "segment", capacity
@@ -224,11 +223,15 @@ class DashTable:
         """(batching, capacity) for a read batch: the fused single-dispatch
         path for small batches (its whole point is killing per-stage launch
         overhead), the Pallas fingerprint path for large batches on eligible
-        configs, per-key vmap otherwise."""
-        if seg.size <= self.fused_threshold and ops.fused_search_eligible(self.cfg):
-            return "fused", None
+        configs, per-key vmap otherwise. Both routed kernels get the exact
+        per-segment lane capacity, so no lane overflows to the per-key path."""
+        from repro.kernels import fused    # local: kernels import core
+        capacity = self._pow2(self._max_per_segment(seg), floor=128)
+        if (seg.size <= self.fused_threshold
+                and fused.fused_search_eligible(self.cfg)):
+            return "fused", capacity
         if seg.size >= 256 and engine.pallas_search_eligible(self.cfg):
-            return "pallas", self._pow2(self._max_per_segment(seg), floor=128)
+            return "pallas", capacity
         return "vmap", None
 
     def _ensure_recovered(self, touched: np.ndarray):

@@ -48,7 +48,6 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import DashConfig, engine, hashing, layout, recovery, smo
@@ -316,8 +315,8 @@ def build_dht_programs(cfg: DashConfig, mesh: Mesh, axes=("data",),
         return jax.tree.map(lambda x: x[None], local), flags[None]
 
     def _wrap(fn, in_specs, out_specs, donate=()):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False),
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False),
                        donate_argnums=donate)
 
     q = q_spec

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import bucket as bk
 from repro.core import hashing, layout
 from repro.core.layout import (DROPPED, EXISTS, INSERTED, NEED_SPLIT,
                                DashConfig, DashState, U32)
@@ -60,8 +61,6 @@ I32 = jnp.int32
 
 BQ = 128          # queries per kernel program (full VPU/MXU row block)
 ROWS = 128        # padded bucket rows per segment plane
-LANES = 128       # padded slot lanes
-NSLOTS = 14
 
 
 # ---------------------------------------------------------------------------
@@ -170,71 +169,14 @@ def _fused_search_direct(cfg: DashConfig, mode: str, state: DashState,
 # fused read — the Pallas mega-kernel (TPU path; interpret mode in tests)
 # ---------------------------------------------------------------------------
 
-def _fold_slots(eq, alloc_bits, va, vb, live):
-    """First-matching-slot fold (bucket_probe's argmax rule) with the value
-    assembled from its 16-bit halves. ``eq``: (BQ, NSLOTS) raw compares,
-    ``alloc_bits``: (BQ,) packed alloc bitmaps, ``live``: (BQ,) lane mask."""
-    ok = jnp.zeros(eq.shape[:1], jnp.bool_)
-    val = jnp.zeros(eq.shape[:1], jnp.int32)
-    for j in range(NSLOTS):
-        hit = eq[:, j] & (((alloc_bits >> j) & 1) == 1) & live
-        take = hit & ~ok
-        val = jnp.where(take, va[:, j] | (vb[:, j] << 16), val)
-        ok = ok | hit
-    return ok, val
-
-
-def _fused_read_block(fp_ref, alloc_ref, khia_ref, khib_ref, kloa_ref,
-                      klob_ref, va_ref, vb_ref, qfp_ref, qb_ref, qpb_ref,
-                      qhia_ref, qhib_ref, qloa_ref, qlob_ref,
-                      found_ref, val_ref, *, nb: int, ns: int):
-    """One (touched-segment, query-block) program: gather the target and
-    probing bucket rows with one-hot MXU matmuls (fp + key halves + value
-    halves share the one-hot), verify keys in 16-bit halves (exact in f32),
-    then fold in the stash rows, which are static rows of the resident
-    plane block — no gather at all."""
-    fp = fp_ref[0].astype(jnp.float32)                   # (ROWS, LANES)
-    alloc = alloc_ref[0]                                 # (ROWS,)
-    planes = [r[0].astype(jnp.float32)
-              for r in (khia_ref, khib_ref, kloa_ref, klob_ref, va_ref, vb_ref)]
-    qfp = qfp_ref[0]
-    q = [r[0] for r in (qhia_ref, qhib_ref, qloa_ref, qlob_ref)]  # (BQ,) i32
-    live = qb_ref[0] >= 0
-    rows = jax.lax.broadcasted_iota(jnp.int32, (BQ, ROWS), 1)
-
-    def bucket_hits(qb):
-        onehot = (rows == qb[:, None]).astype(jnp.float32)
-        gfp = jnp.dot(onehot, fp, preferred_element_type=jnp.float32)
-        gfp = gfp[:, :NSLOTS].astype(jnp.int32)
-        g = [jnp.dot(onehot, p, preferred_element_type=jnp.float32)
-             [:, :NSLOTS].astype(jnp.int32) for p in planes]
-        galloc = jnp.sum(onehot.astype(jnp.int32) * alloc[None, :], axis=1)
-        eq = ((gfp == qfp[:, None])
-              & (g[0] == q[0][:, None]) & (g[1] == q[1][:, None])
-              & (g[2] == q[2][:, None]) & (g[3] == q[3][:, None]))
-        return _fold_slots(eq, galloc, g[4], g[5], live)
-
-    ok_b, v_b = bucket_hits(qb_ref[0])
-    ok_p, v_p = bucket_hits(qpb_ref[0])
-    found = ok_b
-    val = v_b
-    val = jnp.where(ok_p & ~found, v_p, val)
-    found = found | ok_p
-    for s in range(ns):                                  # static stash rows
-        r = nb + s
-        ar = jnp.broadcast_to(alloc[r], (BQ,))
-        fpr = fp[r, :NSLOTS].astype(jnp.int32)
-        pr = [p[r, :NSLOTS].astype(jnp.int32) for p in planes]
-        eq = ((fpr[None, :] == qfp[:, None])
-              & (pr[0][None, :] == q[0][:, None]) & (pr[1][None, :] == q[1][:, None])
-              & (pr[2][None, :] == q[2][:, None]) & (pr[3][None, :] == q[3][:, None]))
-        ok_s, v_s = _fold_slots(
-            eq, ar, jnp.broadcast_to(pr[4][None, :], (BQ, NSLOTS)),
-            jnp.broadcast_to(pr[5][None, :], (BQ, NSLOTS)), live)
-        val = jnp.where(ok_s & ~found, v_s, val)
-        found = found | ok_s
-    found_ref[0] = found.astype(jnp.int32)
-    val_ref[0] = val
+# A touched segment's plane, slot-major: feature k of slot j sits in row
+# k * SROWS + j, bucket row r in column r. The eight features are the
+# fingerprint byte, the allocation bit, and the 16-bit halves of key_hi,
+# key_lo and the value, so one (FEATS, ROWS) @ (ROWS, BQ) one-hot matmul
+# gathers everything a query compares against in its bucket.
+SROWS = 16                        # slot rows per feature (14 real -> 16)
+F_FP, F_ALLOC, F_KHIA, F_KHIB, F_KLOA, F_KLOB, F_VA, F_VB = range(8)
+FEATS = 8 * SROWS                 # = 128, one MXU tile
 
 
 def _halves(x):
@@ -244,125 +186,142 @@ def _halves(x):
             (xi >> U32(16)).astype(jnp.int32))
 
 
+def _fused_read_block(plane_ref, qfp_ref, qb_ref, qpb_ref, qhia_ref,
+                      qhib_ref, qloa_ref, qlob_ref, found_ref, val_ref, *,
+                      nb: int, ns: int):
+    """One (touched-segment, query-block) program, queries on the lanes:
+    one one-hot MXU gather per probed row (target bucket, probing bucket,
+    then each stash row) pulls every feature of that row, the keys are
+    verified in 16-bit halves, and the first matching slot's value is
+    selected. The fp32-precision matmul keeps the 16-bit halves exact."""
+    plane = plane_ref[0].astype(jnp.float32)               # (FEATS, ROWS)
+    qfp = qfp_ref[0]                                       # (1, BQ) each
+    q = [r[0] for r in (qhia_ref, qhib_ref, qloa_ref, qlob_ref)]
+    qb = qb_ref[0]
+    live = qb >= 0
+    rows = jax.lax.broadcasted_iota(jnp.int32, (ROWS, BQ), 0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (SROWS, BQ), 0)
+
+    def row_hits(qrow):
+        onehot = (rows == qrow).astype(jnp.float32)        # (ROWS, BQ)
+        g = jnp.dot(plane, onehot, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32).astype(jnp.int32)
+
+        def f(k):
+            return g[k * SROWS:(k + 1) * SROWS]            # (SROWS, BQ)
+
+        eq = ((f(F_ALLOC) == 1) & (f(F_FP) == qfp)
+              & (f(F_KHIA) == q[0]) & (f(F_KHIB) == q[1])
+              & (f(F_KLOA) == q[2]) & (f(F_KLOB) == q[3]) & live)
+        first = jnp.min(jnp.where(eq, slot, SROWS), axis=0, keepdims=True)
+        val = f(F_VA) | (f(F_VB) << 16)
+        return first < SROWS, jnp.sum(jnp.where(slot == first, val, 0),
+                                      axis=0, keepdims=True)
+
+    found, val = row_hits(qb)
+    for qrow in [qpb_ref[0]] + [jnp.full_like(qb, nb + s) for s in range(ns)]:
+        ok, v = row_hits(qrow)
+        val = jnp.where(ok & ~found, v, val)
+        found = found | ok
+    found_ref[0] = found.astype(jnp.int32)
+    val_ref[0] = val
+
+
 def fused_plane_views(cfg: DashConfig, state: DashState, segments):
-    """Compact, tile-padded plane views for the touched segments only.
+    """(U, FEATS, ROWS) int32 slot-major planes of the given segments only.
 
     ``segments``: (U,) int32 segment ids (may repeat for padding). Stash
-    rows beyond each segment's ``stash_active`` get a zero alloc bitmap so
-    the kernel needs no activation logic. With fingerprints disabled the fp
-    plane is zeroed (queries feed zero bytes -> compare is a no-op)."""
-    BT, ns, NB = cfg.buckets_total, cfg.num_stash, cfg.num_buckets
-    meta = state.meta[segments]                              # (U, BT)
-    alloc = layout.meta_alloc(meta).astype(jnp.int32)
+    rows beyond each segment's ``stash_active`` get a zero alloc bit so the
+    kernel needs no activation logic. With fingerprints disabled the fp
+    feature is zero (queries feed zero bytes -> compare is a no-op)."""
+    BT, ns, NB, SL = (cfg.buckets_total, cfg.num_stash, cfg.num_buckets,
+                      cfg.num_slots)
+    alloc = layout.meta_alloc(state.meta[segments])                  # (U, BT)
     if ns:
         srow = jnp.arange(BT) - NB                           # stash index or <0
         act = state.stash_active[segments][:, None]
         alloc = jnp.where((srow[None, :] >= 0) & (srow[None, :] >= act),
-                          0, alloc)
-    alloc = jnp.pad(alloc, ((0, 0), (0, ROWS - BT)))
-    if cfg.use_fingerprints:
-        fp = jnp.pad(state.fp[segments],
-                     ((0, 0), (0, ROWS - BT), (0, LANES - state.fp.shape[-1])))
-    else:
-        fp = jnp.zeros((segments.shape[0], ROWS, LANES), jnp.uint8)
-
-    def pad16(p):                                            # (U, BT, SL) i32
-        return jnp.pad(p, ((0, 0), (0, ROWS - BT), (0, LANES - p.shape[-1])))
-
-    khia, khib = _halves(state.key_hi[segments])
-    kloa, klob = _halves(state.key_lo[segments])
-    va, vb = _halves(state.val[segments])
-    return (fp, alloc) + tuple(pad16(p) for p in (khia, khib, kloa, klob, va, vb))
+                          U32(0), alloc)
+    abits = ((alloc[..., None] >> jnp.arange(SL, dtype=U32)) & U32(1))
+    fp = state.fp[segments][..., :SL].astype(jnp.int32)
+    if not cfg.use_fingerprints:
+        fp = jnp.zeros_like(fp)
+    feats = ((fp, abits.astype(jnp.int32)) + _halves(state.key_hi[segments])
+             + _halves(state.key_lo[segments]) + _halves(state.val[segments]))
+    x = jnp.stack(feats, axis=1)                             # (U, 8, BT, SL)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, ROWS - BT), (0, SROWS - SL)))
+    return x.transpose(0, 1, 3, 2).reshape(x.shape[0], FEATS, ROWS)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "ns", "interpret"))
 def fused_probe(planes, q_fp, q_b, q_pb, q_hi, q_lo, *, nb: int, ns: int,
-                interpret: bool = True):
+                interpret: bool):
     """The mega-kernel: route+probe+verify over compact touched segments.
 
     Args:
-      planes: output of ``fused_plane_views`` — (fp, alloc, key/value
-        half planes), each (U, ROWS[, LANES]).
+      planes: output of ``fused_plane_views`` — (U, FEATS, ROWS).
       q_fp, q_b, q_pb: (U, C) int32 routed fingerprint bytes and bucket
         rows (-1 = padding lane).
       q_hi, q_lo: (U, C) uint32 routed key words.
+      interpret: run the Pallas interpreter (CPU tests) instead of Mosaic.
 
     Returns (found, val): (U, C) int32 / uint32 per-lane results. The grid
     is (U, C // BQ) with per-segment plane blocks: Pallas's sequential grid
     pipeline prefetches segment u+1's block while u computes — the
-    double-buffering this path is named for.
+    double-buffering this path is named for. Query rows travel as (U, 1, C)
+    so every block satisfies the TPU's (8, 128) tiling rule.
     """
     U, C = q_fp.shape
     assert C % BQ == 0
-    qhia, qhib = _halves(q_hi)
-    qloa, qlob = _halves(q_lo)
+    rows3 = [x.reshape(U, 1, C)
+             for x in (q_fp, q_b, q_pb) + _halves(q_hi) + _halves(q_lo)]
     grid = (U, C // BQ)
-    pspec = pl.BlockSpec((1, ROWS, LANES), lambda s, c: (s, 0, 0))
-    aspec = pl.BlockSpec((1, ROWS), lambda s, c: (s, 0))
-    qspec = pl.BlockSpec((1, BQ), lambda s, c: (s, c))
-    out_i32 = jax.ShapeDtypeStruct((U, C), jnp.int32)
+    pspec = pl.BlockSpec((1, FEATS, ROWS), lambda s, c: (s, 0, 0))
+    qspec = pl.BlockSpec((1, 1, BQ), lambda s, c: (s, 0, c))
+    out_i32 = jax.ShapeDtypeStruct((U, 1, C), jnp.int32)
     found, val = pl.pallas_call(
         functools.partial(_fused_read_block, nb=nb, ns=ns),
         grid=grid,
-        in_specs=[pspec, aspec] + [pspec] * 6 + [qspec] * 7,
+        in_specs=[pspec] + [qspec] * 7,
         out_specs=[qspec, qspec],
         out_shape=[out_i32, out_i32],
         interpret=interpret,
-    )(*planes, q_fp, q_b, q_pb, qhia, qhib, qloa, qlob)
-    return found, val.astype(U32)
+    )(planes, *rows3)
+    return found.reshape(U, C), val.reshape(U, C).astype(U32)
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "ns"))
 def fused_probe_jnp(planes, q_fp, q_b, q_pb, q_hi, q_lo, *, nb: int, ns: int):
-    """Bit-identical jnp lowering of ``fused_probe`` (non-TPU stand-in,
-    and the differential oracle the kernel is pinned against). Same visit
-    order, same first-slot rule, same padded-lane masking."""
-    fp, alloc = planes[0].astype(jnp.int32), planes[1]
-    g16 = [p.astype(jnp.int32) for p in planes[2:]]       # (U, ROWS, LANES)
+    """Bit-identical jnp lowering of ``fused_probe`` (the differential
+    oracle the kernel is pinned against). Same visit order, same
+    first-slot rule, same padded-lane masking."""
     qhia, qhib = _halves(q_hi)
     qloa, qlob = _halves(q_lo)
-    qs = (qhia, qhib, qloa, qlob)
     live = q_b >= 0
-    slot = jnp.arange(NSLOTS)
+    u = jnp.arange(planes.shape[0])[:, None]
 
-    def hits_at(qb):
-        safe = jnp.clip(qb, 0, ROWS - 1)                    # (U, C)
-        u = jnp.arange(safe.shape[0])[:, None]
-        gfp = fp[u, safe][:, :, :NSLOTS]
-        ga = alloc[u, safe]
-        g = [p[u, safe][:, :, :NSLOTS] for p in g16]
-        eq = ((gfp == q_fp[:, :, None])
-              & (g[0] == qhia[:, :, None]) & (g[1] == qhib[:, :, None])
-              & (g[2] == qloa[:, :, None]) & (g[3] == qlob[:, :, None])
-              & (((ga[:, :, None] >> slot) & 1) == 1) & live[:, :, None])
-        ok = jnp.any(eq, axis=-1)
-        j = jnp.argmax(eq, axis=-1)
-        gval = g[4] | (g[5] << 16)
-        v = jnp.where(ok, jnp.take_along_axis(gval, j[:, :, None], axis=-1)[..., 0], 0)
-        return ok, v
+    def row_hits(qrow):
+        g = planes[u, :, jnp.clip(qrow, 0, ROWS - 1)]      # (U, C, FEATS)
+        g = jnp.where((qrow >= 0)[..., None], g, 0)
 
-    ok_b, v_b = hits_at(q_b)
-    ok_p, v_p = hits_at(q_pb)
-    found, val = ok_b, v_b
-    val = jnp.where(ok_p & ~found, v_p, val)
-    found = found | ok_p
-    for s in range(ns):
-        r = nb + s
-        ar = alloc[:, r][:, None]                        # (U, 1)
-        eq = ((fp[:, r, None, :NSLOTS] == q_fp[:, :, None])
-              & (g16[0][:, r, None, :NSLOTS] == qhia[:, :, None])
-              & (g16[1][:, r, None, :NSLOTS] == qhib[:, :, None])
-              & (g16[2][:, r, None, :NSLOTS] == qloa[:, :, None])
-              & (g16[3][:, r, None, :NSLOTS] == qlob[:, :, None])
-              & (((ar[:, :, None] >> slot) & 1) == 1) & live[:, :, None])
-        ok_s = jnp.any(eq, axis=-1)
+        def f(k):
+            return g[..., k * SROWS:(k + 1) * SROWS]       # (U, C, SROWS)
+
+        eq = ((f(F_ALLOC) == 1) & (f(F_FP) == q_fp[..., None])
+              & (f(F_KHIA) == qhia[..., None]) & (f(F_KHIB) == qhib[..., None])
+              & (f(F_KLOA) == qloa[..., None]) & (f(F_KLOB) == qlob[..., None])
+              & live[..., None])
+        val = f(F_VA) | (f(F_VB) << 16)
         j = jnp.argmax(eq, axis=-1)
-        gval = g16[4][:, r, :NSLOTS] | (g16[5][:, r, :NSLOTS] << 16)  # (U, NSLOTS)
-        v_s = jnp.where(ok_s, jnp.take_along_axis(
-            jnp.broadcast_to(gval[:, None, :], eq.shape), j[:, :, None],
-            axis=-1)[..., 0], 0)
-        val = jnp.where(ok_s & ~found, v_s, val)
-        found = found | ok_s
+        return jnp.any(eq, axis=-1), jnp.where(
+            jnp.any(eq, axis=-1),
+            jnp.take_along_axis(val, j[..., None], axis=-1)[..., 0], 0)
+
+    found, val = row_hits(q_b)
+    for qrow in [q_pb] + [jnp.full_like(q_b, nb + s) for s in range(ns)]:
+        ok, v = row_hits(qrow)
+        val = jnp.where(ok & ~found, v, val)
+        found = found | ok
     return found.astype(jnp.int32), val.astype(U32)
 
 
@@ -370,38 +329,49 @@ def fused_probe_jnp(planes, q_fp, q_b, q_pb, q_hi, q_lo, *, nb: int, ns: int):
 # fused read — host-facing dispatch
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 6))
+@functools.partial(jax.jit, static_argnums=(0, 1, 6, 7))
 def _fused_search_routed(cfg: DashConfig, mode: str, state: DashState,
-                         keys_hi, keys_lo, words, capacity: int):
-    """TPU path: route queries to their segments, run the mega-kernel over
-    the (compact) segment set, scatter results back. Capacity-overflow
-    lanes fall back to the per-key probe, mirroring ``_search_batch_routed``."""
+                         keys_hi, keys_lo, words, capacity: int,
+                         interpret: bool):
+    """TPU path: route queries to the segments the batch touches, run the
+    mega-kernel over those segments only, scatter results back.
+    Capacity-overflow lanes fall back to the direct path, mirroring
+    ``_search_batch_routed``. ``interpret=True`` runs the kernel in the
+    Pallas interpreter (the CPU tests); the TPU dispatcher passes False."""
     from repro.kernels import ops
     h1 = hashing.hash1(keys_hi, keys_lo)
     h2 = hashing.hash2(keys_hi, keys_lo)
     fpv = (h2 & U32(0xFF)).astype(jnp.int32)
+    if not cfg.use_fingerprints:
+        fpv = jnp.zeros_like(fpv)
     seg, b = ops.locate_batch(cfg, mode, state, h1)
     NB = cfg.num_buckets
-    lanes, src, keep = ops.route_lanes(
-        seg, (fpv, b.astype(jnp.int32), keys_hi, keys_lo, seg >= 0),
-        cfg.max_segments, capacity, (0, -1, 0, 0, False))
-    q_fp, q_b, q_hi, q_lo, q_valid = lanes
-    q_b = jnp.where(q_valid, q_b, -1)
-    q_pb = jnp.where(q_valid, (q_b + 1) & (NB - 1), -1)
-    segments = jnp.arange(cfg.max_segments, dtype=jnp.int32)
-    planes = fused_plane_views(cfg, state, segments)
-    interp = jax.default_backend() != "tpu"
-    f, v = fused_probe(planes, jnp.where(q_valid, q_fp, -1), q_b, q_pb,
-                       q_hi, q_lo, nb=NB, ns=cfg.num_stash, interpret=interp)
     n = keys_hi.shape[0]
+    segments, cid = ops.touched_segments(seg, min(n, cfg.max_segments))
+    lanes, src, keep = ops.route_lanes(
+        cid, (fpv, b.astype(jnp.int32), keys_hi, keys_lo),
+        segments.shape[0], capacity, (-1, -1, 0, 0))
+    q_fp, q_b, q_hi, q_lo = lanes
+    q_pb = jnp.where(q_b >= 0, (q_b + 1) & (NB - 1), -1)
+    f, v = fused_probe(fused_plane_views(cfg, state, segments), q_fp, q_b,
+                       q_pb, q_hi, q_lo, nb=NB, ns=cfg.num_stash,
+                       interpret=interpret)
     flatf, flatv = f.reshape(-1) != 0, v.reshape(-1)
     srcf = src.reshape(-1)
     ok = jnp.clip(srcf, 0)
     found = jnp.zeros((n,), jnp.bool_).at[ok].max(jnp.where(srcf >= 0, flatf, False))
     val = jnp.zeros((n,), U32).at[ok].max(jnp.where(srcf >= 0, flatv, U32(0)))
-    direct = _fused_search_direct(cfg, mode, state, keys_hi, keys_lo, words)
-    return (jnp.where(keep, found, direct[0]),
-            jnp.where(keep, val, direct[1]))
+    if capacity >= n:
+        return found, val           # no lane can overflow: keep is all-True
+
+    def fallback(_):
+        return _fused_search_direct(cfg, mode, state, keys_hi, keys_lo, words)
+
+    def none(_):
+        return jnp.zeros_like(found), jnp.zeros_like(val)
+
+    f2, v2 = jax.lax.cond(jnp.any(~keep), fallback, none, None)
+    return jnp.where(keep, found, f2), jnp.where(keep, val, v2)
 
 
 def fused_search(cfg: DashConfig, mode: str, state: DashState,
@@ -412,7 +382,8 @@ def fused_search(cfg: DashConfig, mode: str, state: DashState,
     Non-TPU hosts always take the direct-addressed lowering (one gather +
     one dense compare — no routing, which is the whole point at small
     batches). TPU hosts take the routed mega-kernel when the config is in
-    its span, the direct lowering otherwise."""
+    its span, the direct lowering otherwise. ``capacity`` is the lanes per
+    touched segment; the default (the padded batch) can never overflow."""
     n = keys_hi.shape[0]
     if words is None:
         words = jnp.zeros((n, cfg.key_heap_words), U32)
@@ -420,7 +391,7 @@ def fused_search(cfg: DashConfig, mode: str, state: DashState,
         if capacity is None:
             capacity = max(BQ, 1 << (max(n - 1, 1)).bit_length())
         return _fused_search_routed(cfg, mode, state, keys_hi, keys_lo,
-                                    words, capacity)
+                                    words, capacity, False)
     return _fused_search_direct(cfg, mode, state, keys_hi, keys_lo, words)
 
 
@@ -570,10 +541,7 @@ def _merged_insert_body(cfg: DashConfig, st: DashState, ln):
     mv_dst_b = jnp.where(code == 2, pb2, bm1)
     mv_dst_slot = ffs(meta[mv_dst_b])                   # pre-state; branch guarantees room
     mv_member = code == 2                               # dispA re-homes as member-set
-    mk_hi = st.key_hi[0, mv_src_b, mv_src_slot]
-    mk_lo = st.key_lo[0, mv_src_b, mv_src_slot]
-    mk_v = st.val[0, mv_src_b, mv_src_slot]
-    mk_fp = st.fp[0, mv_src_b, mv_src_slot]
+    mk_hi, mk_lo, mk_v, mk_fp = bk.read_slot(st, 0, mv_src_b, mv_src_slot)
 
     sb = NB + st_j
     new_b = jnp.where(code == 1, ins_b,
@@ -588,8 +556,8 @@ def _merged_insert_body(cfg: DashConfig, st: DashState, ln):
     new_row = jnp.where(committed, new_b, OOB)
 
     def write2(plane, x_mv, x_new):
-        plane = plane.at[0, mv_row, mv_dst_slot].set(x_mv, mode="drop")
-        return plane.at[0, new_row, new_slot].set(x_new, mode="drop")
+        plane = bk.set_slot(plane, 0, mv_row, mv_dst_slot, x_mv)
+        return bk.set_slot(plane, 0, new_row, new_slot, x_new)
 
     key_hi = write2(st.key_hi, mk_hi, hi)
     key_lo = write2(st.key_lo, mk_lo, lo)
@@ -650,10 +618,10 @@ def _merged_insert_body(cfg: DashConfig, st: DashState, ln):
             ometa = ometa.at[0, jnp.where(is_st & ~ok1 & ok2, pb, OOB_NB)
                              ].set(om_pb_set, mode="drop")
             ofp = st.ofp
-            ofp = ofp.at[0, jnp.where(is_st & ok1, b, OOB_NB), ofs1
-                         ].set(fpv, mode="drop")
-            ofp = ofp.at[0, jnp.where(is_st & ~ok1 & ok2, pb, OOB_NB), ofs2
-                         ].set(fpv, mode="drop")
+            ofp = bk.set_slot(ofp, 0, jnp.where(is_st & ok1, b, OOB_NB),
+                              ofs1, fpv)
+            ofp = bk.set_slot(ofp, 0, jnp.where(is_st & ~ok1 & ok2, pb,
+                                                OOB_NB), ofs2, fpv)
             ver = ver.at[0, jnp.where(is_st, jnp.where(~ok1 & ok2, pb, b), OOB)
                          ].add(U32(2), mode="drop")
             st = st._replace(ometa=ometa, ofp=ofp)

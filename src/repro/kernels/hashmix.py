@@ -31,8 +31,9 @@ def _mix_block(hi_ref, lo_ref, h1_ref, h2_ref, fp_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bulk_hash(key_hi, key_lo, *, interpret=True):
-    """(h1, h2, fp) for a (N,) uint32-pair key batch. N % BLOCK == 0."""
+def bulk_hash(key_hi, key_lo, *, interpret: bool):
+    """(h1, h2, fp) for a (N,) uint32-pair key batch. N % BLOCK == 0.
+    ``interpret=True`` runs the Pallas interpreter (CPU tests)."""
     n = key_hi.shape[0]
     assert n % BLOCK == 0, "pad key batches to BLOCK"
     spec = pl.BlockSpec((BLOCK,), lambda i: (i,))

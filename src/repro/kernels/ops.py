@@ -16,10 +16,12 @@ the segment-parallel write engine (core/engine.py) via ``route_writes``.
 Ranking is sort-based (O(Q log Q)), not the dense one-hot+cumsum (O(Q*S))
 it replaced, so routing cost scales with batch size, not directory size.
 
-``interpret=True`` (the default off-TPU) swaps pl.pallas_call for the
-bit-identical jnp lowerings — the Pallas interpreter's per-program overhead
-is not the hot path's job; on TPU pass interpret=False, shapes/BlockSpecs
-are already MXU/VPU aligned.
+``interpret`` has no default: ``True`` (the CPU tests) swaps
+pl.pallas_call for the bit-identical jnp lowerings — the Pallas
+interpreter's per-program overhead is not the hot path's job — and the TPU
+path passes ``False``. The routed planes cover only the segments a batch
+touches (``touched_segments``), so their size follows the batch, not the
+segment pool.
 
 The fused small-batch latency path (kernels/fused.py) is re-exported here:
 ``fused_search`` / ``fused_insert`` collapse the route->probe->verify /
@@ -39,21 +41,38 @@ from . import probe as probe_kernel
 from .fused import (fused_insert, fused_insert_eligible,  # noqa: F401
                     fused_kernel_eligible, fused_probe, fused_probe_jnp,
                     fused_search, fused_search_eligible)
-from .hashmix import BLOCK, bulk_hash
 from .probe import LANES, NSLOTS, ROWS, fingerprint_probe
 
 I32 = jnp.int32
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def plane_views(cfg: DashConfig, state: DashState):
-    """(fp_padded (S,128,128) u8, alloc (S,128) i32) from table state."""
-    S, BT = cfg.max_segments, cfg.buckets_total
-    fp = jnp.zeros((S, ROWS, LANES), jnp.uint8)
-    fp = fp.at[:, :BT, :16].set(state.fp)
-    alloc = jnp.zeros((S, ROWS), jnp.int32)
-    alloc = alloc.at[:, :BT].set(layout.meta_alloc(state.meta).astype(jnp.int32))
+def plane_views(cfg: DashConfig, state: DashState, segments):
+    """(fp_padded (U,128,128) u8, alloc (U,128) i32) of the given segments."""
+    BT = cfg.buckets_total
+    U = segments.shape[0]
+    fp = jnp.zeros((U, ROWS, LANES), jnp.uint8)
+    fp = fp.at[:, :BT, :16].set(state.fp[segments])
+    alloc = jnp.zeros((U, ROWS), jnp.int32)
+    alloc = alloc.at[:, :BT].set(
+        layout.meta_alloc(state.meta[segments]).astype(jnp.int32))
     return fp, alloc
+
+
+def touched_segments(seg, num: int):
+    """The distinct segment ids of a batch, and each item's row among them.
+
+    Returns ``(segments, rows)``: ``segments`` is (num,) int32, ascending,
+    padded with segment 0 (no item maps to a padding row); ``rows[i]`` is
+    the index of ``seg[i]`` in it, -1 where ``seg[i] < 0``. ``num`` must be
+    at least the number of distinct ids (the batch size or the pool size
+    bounds it), so the routed kernels build plane views and lanes for what
+    a batch touches, not for the whole segment pool."""
+    pad = jnp.iinfo(I32).max
+    seg = seg.astype(I32)
+    ids = jnp.unique(jnp.where(seg >= 0, seg, pad), size=num, fill_value=pad)
+    rows = jnp.where(seg >= 0, jnp.searchsorted(ids, seg).astype(I32), -1)
+    return jnp.where(ids == pad, 0, ids), rows
 
 
 # ---------------------------------------------------------------------------
@@ -115,23 +134,26 @@ def route_queries(cfg: DashConfig, state: DashState, keys_hi, keys_lo,
     """Group a query batch by segment with fixed capacity (MoE-style dispatch;
     the intra-host analog of the DHT's all_to_all routing).
 
-    Returns (q_fp, q_b, q_pb, q_src, keep): (S, C) planes; q_src maps back to
-    the original batch position (-1 = empty lane); ``keep`` is False for
-    capacity-dropped queries (resolved by the caller on the per-key path)."""
+    Returns (q_fp, q_b, q_pb, q_src, keep, segments): (U, C) planes whose
+    row u serves ``segments[u]``, one row per segment the batch touches
+    (``touched_segments``); q_src maps back to the original batch position
+    (-1 = empty lane); ``keep`` is False for capacity-dropped queries
+    (resolved by the caller on the per-key path)."""
     h1 = hashing.hash1(keys_hi, keys_lo)
     h2 = hashing.hash2(keys_hi, keys_lo)
     seg, b = locate_batch(cfg, mode, state, h1)
     pb = (b + 1) & (cfg.num_buckets - 1)
     fp = (h2 & jnp.uint32(0xFF)).astype(jnp.int32)
+    segments, rows = touched_segments(
+        seg, min(keys_hi.shape[0], cfg.max_segments))
     (q_fp, q_b, q_pb), q_src, keep = route_lanes(
-        seg, (fp, b, pb), cfg.max_segments, capacity, (0, -1, -1))
-    return q_fp, q_b, q_pb, q_src, keep
+        rows, (fp, b, pb), segments.shape[0], capacity, (0, -1, -1))
+    return q_fp, q_b, q_pb, q_src, keep, segments
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4, 5, 6))
 def probe_routed(cfg: DashConfig, state: DashState, keys_hi, keys_lo,
-                 capacity: int = 256, interpret: bool = True,
-                 mode: str = "eh"):
+                 capacity: int, interpret: bool, mode: str = "eh"):
     """End-to-end batched search through the Pallas fingerprint kernel.
 
     Covers target+probing buckets via the MXU gather and the (few) stash
@@ -145,15 +167,16 @@ def probe_routed(cfg: DashConfig, state: DashState, keys_hi, keys_lo,
     Requires inline keys + fingerprints + a <=2 bucket probe window (the
     engine dispatcher gates on exactly that, falling back to the vmap path).
 
-    ``interpret=True`` (non-TPU hosts) runs the kernel's bit-identical jnp
+    ``interpret=True`` (the CPU tests) runs the kernel's bit-identical jnp
     lowering instead of the Pallas interpreter — same routed planes, same
     bitmaps, none of the per-program interpreter overhead.
     """
     Q = keys_hi.shape[0]
-    S, NB, SL = cfg.max_segments, cfg.num_buckets, cfg.num_slots
-    fp_pad, alloc = plane_views(cfg, state)
-    q_fp, q_b, q_pb, q_src, keep = route_queries(cfg, state, keys_hi, keys_lo,
-                                                 capacity, mode)
+    NB, SL = cfg.num_buckets, cfg.num_slots
+    q_fp, q_b, q_pb, q_src, keep, segments = route_queries(
+        cfg, state, keys_hi, keys_lo, capacity, mode)
+    S = segments.shape[0]
+    fp_pad, alloc = plane_views(cfg, state, segments)
     if interpret:
         bits_b, bits_pb, _free_b, _free_pb = probe_kernel.fingerprint_probe_jnp(
             fp_pad, alloc, q_fp, q_b, q_pb)
@@ -163,7 +186,7 @@ def probe_routed(cfg: DashConfig, state: DashState, keys_hi, keys_lo,
 
     # verify fingerprint hits with real key compares — one row gather per
     # plane (the paper's 'amortized one key load': only matched rows hit)
-    seg_ids = jnp.broadcast_to(jnp.arange(S)[:, None], q_b.shape).reshape(-1)
+    seg_ids = jnp.broadcast_to(segments[:, None], q_b.shape).reshape(-1)
     flat_src = q_src.reshape(-1)
     hi_r = jnp.where(flat_src >= 0, keys_hi[jnp.clip(flat_src, 0)], 0)
     lo_r = jnp.where(flat_src >= 0, keys_lo[jnp.clip(flat_src, 0)], 0)
@@ -189,18 +212,19 @@ def probe_routed(cfg: DashConfig, state: DashState, keys_hi, keys_lo,
     # never-activated stash bucket has no allocated slots.
     if cfg.num_stash > 0:
         C = q_fp.shape[1]
-        st_alloc = layout.meta_alloc(state.meta[:, NB:NB + cfg.num_stash])
+        st = slice(NB, NB + cfg.num_stash)
+        st_alloc = layout.meta_alloc(state.meta[segments, st])
         slot_ids = jnp.arange(SL, dtype=jnp.uint32)
         st_live = ((st_alloc[..., None] >> slot_ids) & 1) == 1   # (S, ns, SL)
-        st_hi = state.key_hi[:, NB:NB + cfg.num_stash, :SL]
-        st_lo = state.key_lo[:, NB:NB + cfg.num_stash, :SL]
-        st_val = state.val[:, NB:NB + cfg.num_stash, :SL]
+        st_hi = state.key_hi[segments, st, :SL]
+        st_lo = state.key_lo[segments, st, :SL]
+        st_val = state.val[segments, st, :SL]
         hi_l = hi_r.reshape(S, C)[:, :, None, None]
         lo_l = lo_r.reshape(S, C)[:, :, None, None]
         m = (st_live[:, None] & (st_hi[:, None] == hi_l) &
              (st_lo[:, None] == lo_l) & (q_src >= 0)[..., None, None])
         if cfg.use_fingerprints:
-            st_fp = state.fp[:, NB:NB + cfg.num_stash, :SL].astype(jnp.int32)
+            st_fp = state.fp[segments, st, :SL].astype(jnp.int32)
             m = m & (st_fp[:, None] == q_fp[:, :, None, None])
         ok_s = jnp.any(m, axis=(2, 3)).reshape(-1)               # (S*C,)
         val_s = jnp.max(jnp.where(m, jnp.broadcast_to(st_val[:, None], m.shape),
@@ -265,22 +289,14 @@ def probe_direct(cfg: DashConfig, state: DashState, keys_hi, keys_lo,
     return found, values
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4, 5, 6))
+@functools.partial(jax.jit, static_argnums=(0, 1, 4))
 def route_writes(cfg: DashConfig, mode: str, state: DashState,
-                 payload, capacity: int, with_hints: bool = False,
-                 interpret: bool = True):
+                 payload, capacity: int):
     """Route a *write* batch by segment, carrying full key/value lanes.
 
     ``payload`` is (keys_hi, keys_lo, vals, words, valid). Returns
     ``(lanes, src, keep)`` where lanes is the dict the segment-parallel
     engine scans: hi/lo/val/words/b/h1/h2/valid, each (S, C[, W]).
-
-    With ``with_hints=True`` the routed lanes are additionally pushed through
-    the Pallas fingerprint pass over the *same* plane views the search path
-    uses, returning per-lane (match_bits_b, match_bits_pb, free_slots_b,
-    free_slots_pb). The free-slot bitmaps are advisory (pre-batch state —
-    intra-batch inserts invalidate them): available to host-side admission
-    and capacity prechecks, never for the commit decision.
     """
     keys_hi, keys_lo, vals, words, valid = payload
     h1 = hashing.hash1(keys_hi, keys_lo)
@@ -293,25 +309,25 @@ def route_writes(cfg: DashConfig, mode: str, state: DashState,
         (0, 0, 0, 0, 0, 0, 0, False))
     lanes = dict(zip(("hi", "lo", "val", "words", "b", "h1", "h2", "valid"),
                      planes))
-    if not with_hints:
-        return lanes, src, keep
-    fp_pad, alloc = plane_views(cfg, state)
+    return lanes, src, keep
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def write_hints(cfg: DashConfig, state: DashState, lanes, interpret: bool):
+    """Push ``route_writes`` lanes through the fingerprint pass over the
+    *same* plane views the search path uses: per-lane (match_bits_b,
+    match_bits_pb, free_slots_b, free_slots_pb). The free-slot bitmaps are
+    advisory (pre-batch state — intra-batch inserts invalidate them):
+    available to host-side admission and capacity prechecks, never for the
+    commit decision. ``interpret=True`` runs the jnp lowering."""
+    fp_pad, alloc = plane_views(
+        cfg, state, jnp.arange(cfg.max_segments, dtype=I32))
     q_fp = (lanes["h2"] & jnp.uint32(0xFF)).astype(jnp.int32)
     q_b = jnp.where(lanes["valid"], lanes["b"].astype(jnp.int32), -1)
     q_pb = jnp.where(lanes["valid"],
                      (lanes["b"].astype(jnp.int32) + 1) & (cfg.num_buckets - 1),
                      -1)
-    probe_fn = (probe_kernel.fingerprint_probe_jnp if interpret
-                else functools.partial(fingerprint_probe, interpret=False))
-    hints = probe_fn(fp_pad, alloc, q_fp, q_b, q_pb)
-    return lanes, src, keep, hints
-
-
-def bulk_hash_padded(keys_hi, keys_lo, interpret: bool = True):
-    """bulk_hash with automatic BLOCK padding (host convenience)."""
-    n = keys_hi.shape[0]
-    pad = (-n) % BLOCK
-    hi = jnp.pad(keys_hi, (0, pad))
-    lo = jnp.pad(keys_lo, (0, pad))
-    h1, h2, fp = bulk_hash(hi, lo, interpret=interpret)
-    return h1[:n], h2[:n], fp[:n]
+    if interpret:
+        return probe_kernel.fingerprint_probe_jnp(fp_pad, alloc, q_fp, q_b,
+                                                  q_pb)
+    return fingerprint_probe(fp_pad, alloc, q_fp, q_b, q_pb, interpret=False)

@@ -8,8 +8,8 @@ SIMD before touching any key (Sec. 4.2). On TPU the analogous unit is the VPU
 Layout adaptation (DESIGN.md Sec. 2): a segment's fingerprint plane is padded
 to a (128, 128) uint8 tile — 128 bucket rows (64 normal + stash + pad) by 128
 lanes (first 16 = slot fingerprints). 128 is the MXU's native dimension, so
-the one-hot gather `one_hot(q_b) @ fp_plane` is a single aligned MXU pass,
-and the fingerprint-compare runs on full VPU lanes. This mirrors the paper's
+the one-hot gather `fp_plane^T @ one_hot(q_b)^T` is a single aligned MXU
+pass, and the fingerprint compare runs with the queries on full VPU lanes. This mirrors the paper's
 choice of a 256-byte bucket (the Optane block): size the probe unit to the
 hardware's native transfer/compute block.
 
@@ -38,35 +38,49 @@ LANES = 128       # padded fingerprint lanes (16 real -> 128)
 NSLOTS = 14
 
 
+SROWS = 16        # slot rows of a gathered bucket (14 real -> 16)
+
+
 def _probe_block(fp_ref, alloc_ref, qfp_ref, qb_ref, qpb_ref,
                  out_b_ref, out_pb_ref, free_b_ref, free_pb_ref):
-    """One (segment, query-block) program."""
-    fp = fp_ref[0].astype(jnp.float32)              # (ROWS, LANES) — small ints, exact in f32
-    alloc = alloc_ref[0]                            # (ROWS,) int32 — 14-bit bitmaps
-    qfp = qfp_ref[0]                                # (BQ,) int32 fingerprint values
-    rows = jax.lax.broadcasted_iota(jnp.int32, (BQ, ROWS), 1)
+    """One (segment, query-block) program, queries on the lanes.
+
+    The one-hot is built transposed, (ROWS, BQ), so every per-query value
+    is a (1, BQ) row and every per-slot value a (SROWS, BQ) tile: the MXU
+    gathers the query's bucket column out of the fingerprint plane and out
+    of the unpacked allocation bits, and the slot fold is a sublane
+    reduction. Values are below 256, so the gather is exact at any matmul
+    precision."""
+    fp = fp_ref[0].astype(jnp.int32).astype(jnp.float32)   # (ROWS, LANES)
+    alloc = alloc_ref[0]                                   # (1, ROWS) 14-bit bitmaps
+    qfp = qfp_ref[0]                                       # (1, BQ)
+    abits = ((alloc >> jax.lax.broadcasted_iota(jnp.int32, (SROWS, ROWS), 0))
+             & 1).astype(jnp.float32)                      # (SROWS, ROWS)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (ROWS, BQ), 0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (SROWS, BQ), 0)
+    real = slot < NSLOTS
 
     def gather_and_match(qb):
-        onehot = (rows == qb[:, None]).astype(jnp.float32)          # (BQ, ROWS)
-        gfp = jnp.dot(onehot, fp, preferred_element_type=jnp.float32)  # MXU gather
-        gfp = gfp[:, :NSLOTS].astype(jnp.int32)                      # (BQ, 14)
-        galloc = jnp.sum(onehot.astype(jnp.int32) * alloc[None, :], axis=1)  # (BQ,)
-        eq = gfp == qfp[:, None]                                     # (BQ, 14)
-        bits = jnp.zeros((BQ,), jnp.int32)
-        for j in range(NSLOTS):
-            abit = (galloc >> j) & 1
-            bits = bits | ((eq[:, j].astype(jnp.int32) & abit) << j)
+        onehot = (rows == qb).astype(jnp.float32)                    # (ROWS, BQ)
+        gfp = jax.lax.dot_general(                                   # fp^T @ onehot
+            fp, onehot, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)[:SROWS]              # (SROWS, BQ)
+        galloc = jnp.dot(abits, onehot, preferred_element_type=jnp.float32)
+        live = (galloc > 0.5) & real
+        hit = live & (gfp.astype(jnp.int32) == qfp)
+        bits = jnp.sum(hit.astype(jnp.int32) << slot, axis=0, keepdims=True)
         # free-slot bitmap of the same gathered bucket (reused by the insert
         # router — same plane view, no extra gather); 0 for padding lanes
-        free = jnp.where(qb < 0, 0, (~galloc) & ((1 << NSLOTS) - 1))
-        return bits, free
+        free = jnp.sum(((galloc < 0.5) & real).astype(jnp.int32) << slot,
+                       axis=0, keepdims=True)
+        return bits, jnp.where(qb < 0, 0, free)
 
     out_b_ref[0], free_b_ref[0] = gather_and_match(qb_ref[0])
     out_pb_ref[0], free_pb_ref[0] = gather_and_match(qpb_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fingerprint_probe(fp_padded, alloc, q_fp, q_b, q_pb, *, interpret=True):
+def fingerprint_probe(fp_padded, alloc, q_fp, q_b, q_pb, *, interpret: bool):
     """Batched fingerprint probe over routed queries.
 
     Args:
@@ -74,6 +88,7 @@ def fingerprint_probe(fp_padded, alloc, q_fp, q_b, q_pb, *, interpret=True):
       alloc:     (S, ROWS) int32 — per-bucket allocation bitmaps (14 bits).
       q_fp:      (S, C) int32 — query fingerprint bytes, routed per segment.
       q_b, q_pb: (S, C) int32 — target/probing bucket rows (-1 = padding).
+      interpret: run the Pallas interpreter (CPU tests) instead of Mosaic.
 
     Returns:
       (bits_b, bits_pb, free_b, free_pb): (S, C) int32 — per-query 14-bit
@@ -81,24 +96,30 @@ def fingerprint_probe(fp_padded, alloc, q_fp, q_b, q_pb, *, interpret=True):
       bitmaps of the same buckets (bit j set = slot j unallocated; 0 on
       padding lanes). The free bitmaps let the insert router reuse this
       single gather pass: ``ctz(free_b)`` is Alg. 1's first-free-slot.
+
+    The per-segment rows are passed as (S, 1, ·) arrays so that every block's
+    last two dimensions are (1, full) or (1, BQ): the TPU's (8, 128) tiling
+    rule holds for any S.
     """
     S, C = q_fp.shape
     assert C % BQ == 0, "query capacity must be a multiple of BQ"
     grid = (S, C // BQ)
-    qspec = pl.BlockSpec((1, BQ), lambda s, c: (s, c))
-    out_i32 = jax.ShapeDtypeStruct((S, C), jnp.int32)
-    return pl.pallas_call(
+    qspec = pl.BlockSpec((1, 1, BQ), lambda s, c: (s, 0, c))
+    out_i32 = jax.ShapeDtypeStruct((S, 1, C), jnp.int32)
+    rows3 = [x.reshape(S, 1, C) for x in (q_fp, q_b, q_pb)]
+    outs = pl.pallas_call(
         _probe_block,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, ROWS, LANES), lambda s, c: (s, 0, 0)),  # fp plane: VMEM-resident per segment
-            pl.BlockSpec((1, ROWS), lambda s, c: (s, 0)),
+            pl.BlockSpec((1, 1, ROWS), lambda s, c: (s, 0, 0)),
             qspec, qspec, qspec,
         ],
         out_specs=[qspec, qspec, qspec, qspec],
         out_shape=[out_i32, out_i32, out_i32, out_i32],
         interpret=interpret,
-    )(fp_padded, alloc, q_fp, q_b, q_pb)
+    )(fp_padded, alloc.reshape(S, 1, ROWS), *rows3)
+    return tuple(o.reshape(S, C) for o in outs)
 
 
 def _match_jnp(fp_padded, alloc, q_fp, qb):

@@ -6,6 +6,16 @@ touches jax device state — the dry-run must set XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: JAX 0.9 defaults to Explicit axes,
+    under which the COW publish gather and the DHT's device_put of stacked
+    shard state need explicit shardings. ``devices`` defaults to all of
+    ``jax.devices()``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,9 +24,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     outer data-parallel axis crossing DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 4):
     """Small host-device mesh for subprocess tests (8 fake devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

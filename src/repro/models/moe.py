@@ -107,7 +107,6 @@ def moe_apply_shardmap(params, cfg: MoEConfig, x, mesh, batch_axes,
     half the wire of fp32 grad sync), runs the dispatch/FFN entirely locally,
     and touches the fabric for nothing else. SPMD partitioner guessing is out
     of the loop — the collective schedule is exactly what is written here."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -125,12 +124,12 @@ def moe_apply_shardmap(params, cfg: MoEConfig, x, mesh, batch_axes,
         y, aux = _moe_math(cfg, xl, router, wg, wu, wd, cap)
         return y, jax.lax.pmean(aux, bx)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(bx), P(wx, None), P(None, wx, None),
                   P(None, wx, None), P(None, None, wx)),
         out_specs=(P(bx), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
     return y, aux
@@ -144,7 +143,6 @@ def moe_apply_ep_shardmap(params, cfg: MoEConfig, x, mesh, bx, ep_axis,
     (~2*S*K*d bf16/device/layer) instead of expert weights, which wins when
     expert weights >> routed activations (phi3.5: 16 experts of 6400-ff vs
     4k tokens). Requires n_experts % size(ep_axis) == 0."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -213,12 +211,12 @@ def moe_apply_ep_shardmap(params, cfg: MoEConfig, x, mesh, bx, ep_axis,
         y = jax.vmap(collect_row)(ysrc, dst, keep, w)
         return y, jax.lax.pmean(aux, bx)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(bx), P(fx, None), P(ep_axis, fx, None),
                   P(ep_axis, fx, None), P(ep_axis, None, fx)),
         out_specs=(P(bx), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
     return y, aux

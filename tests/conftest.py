@@ -1,22 +1,15 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see 1 device; multi-device tests run in subprocesses (test_dryrun/test_dht).
 
-The JAX persistent compilation cache is wired up repo-locally (.jax_cache/,
-gitignored) through benchmarks.common.enable_compilation_cache, which is
-OPT-IN via REPRO_COMPILATION_CACHE=1: on this container's jaxlib
-(0.4.36/CPU), executables deserialized from the cache mishandle buffer
-donation — donated pass-through planes come back corrupted
-nondeterministically (test_batch_parallel's scan-vs-segment differential
-caught lh_dir diverging on a delete that touches neither) and large cached
-SMO dispatches can crash. See benchmarks/common.py for the full note; flip
-the env var once the deployment jaxlib handles donation in deserialized
-executables."""
+JAX's persistent compilation cache is on (benchmarks.common.
+enable_compilation_cache: ``$JAX_COMPILATION_CACHE_DIR`` or the checkout's
+gitignored ``.jax_cache/``)."""
 import numpy as np
 import pytest
 
 from benchmarks.common import enable_compilation_cache
 
-enable_compilation_cache()      # no-op unless REPRO_COMPILATION_CACHE=1
+enable_compilation_cache()
 
 
 @pytest.fixture(scope="session")

@@ -10,8 +10,10 @@ batch, stash overflow, padding (valid) masks and NEED_SPLIT batches.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import DashConfig, DashEH, engine, hashing, layout
+from repro.core import bucket as bk
 from repro.core.layout import DROPPED, NEED_SPLIT
 from tests._hypothesis_compat import given, settings, st
 from tests.conftest import unique_keys
@@ -160,3 +162,32 @@ def test_update_batch_valid_mask():
         v = np.asarray(v)
         assert (v[:B // 2] == 777).all(), batching
         assert (v[B // 2:] == np.arange(B // 2, B)).all(), batching
+
+
+# (b, slot) pairs on a 6 x 5 plane; the last two are out of range
+SLOT_CASES = [(0, 0), (5, 4), (2, 3), (6, 1), (3, 5)]
+
+
+@pytest.mark.parametrize("segments", [1, 3], ids=["view", "table"])
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.uint32])
+def test_slot_access_matches_scatter(segments, dtype):
+    """``bucket.set_slot``/``get_slot`` equal the element scatter and
+    gather on a whole table (S > 1) and on the single-segment view the
+    segment-parallel engines use (S == 1, there a masked select), vmapped
+    as the engines run it; an out-of-range row or slot writes nothing."""
+    rng = np.random.default_rng(segments)
+    plane = jnp.asarray(rng.integers(0, 256, (segments, 6, 5)), dtype)
+    x = jnp.asarray(7, dtype)
+    seg = segments - 1
+    for b, slot in SLOT_CASES:
+        want = plane.at[seg, b, slot].set(x, mode="drop")
+        assert np.array_equal(bk.set_slot(plane, seg, b, slot, x), want)
+        if b < 6 and slot < 5:
+            assert bk.get_slot(plane, seg, b, slot) == plane[seg, b, slot]
+    bs = jnp.asarray([c[0] for c in SLOT_CASES], jnp.int32)
+    ss = jnp.asarray([c[1] for c in SLOT_CASES], jnp.int32)
+    views = jnp.broadcast_to(plane[seg], (len(SLOT_CASES),) + plane.shape[1:])
+    got = jax.vmap(lambda v, b, s: bk.set_slot(v[None], 0, b, s, x)[0])(
+        views, bs, ss)
+    for i, (b, slot) in enumerate(SLOT_CASES):
+        assert np.array_equal(got[i], plane[seg].at[b, slot].set(x, mode="drop"))

@@ -163,7 +163,6 @@ def test_compressed_psum_over_pod_axis():
     out = run_sub("""
         import jax, numpy as np
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_test_mesh
         from repro.parallel import compression
@@ -177,8 +176,8 @@ def test_compressed_psum_over_pod_axis():
             out, res = compression.compressed_psum(grads, res, "data")
             return out["w"][None], res["w"][None]
 
-        f = shard_map(sync, mesh=mesh, in_specs=(P("data"),),
-                      out_specs=(P("data"), P("data")), check_rep=False)
+        f = jax.shard_map(sync, mesh=mesh, in_specs=(P("data"),),
+                          out_specs=(P("data"), P("data")), check_vma=False)
         mean_c, residual = f(g)
         true_mean = np.asarray(g).mean(axis=0)
         got = np.asarray(mean_c)[0]

@@ -16,6 +16,7 @@ import dataclasses as dc
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import DashConfig, DashEH, engine, hashing, layout
 from repro.kernels import fused
@@ -199,6 +200,52 @@ def test_fused_kernel_matches_lowering_and_vmap():
     got_v[srcf[m]] = flatv[m]
     assert (got_f[keep_np] == np.asarray(f_ref)[keep_np]).all()
     assert (got_v[keep_np] == np.asarray(v_ref)[keep_np]).all()
+
+
+def _routed_case(case):
+    """(cfg, state, hi, lo, capacity) for one ``_fused_search_routed`` case."""
+    from repro.kernels import ops
+    cfg = CONFIGS["no_fp" if case == "no_fp" else "default"]
+    rng = np.random.default_rng(0x5EA)
+    hi, lo = _keys(rng, 1024)
+    state, st, _ = engine.insert_batch(
+        cfg, "eh", layout.make_state(cfg, "eh"), hi[:768], lo[:768],
+        jnp.arange(1, 769, dtype=jnp.uint32), batching="scan")
+    # hits, then misses (keys 768.. were never inserted)
+    q_hi, q_lo = hi[256:1024], lo[256:1024]
+    seg, _ = ops.locate_batch(cfg, "eh", state,
+                              hashing.hash1(q_hi, q_lo))
+    seg = np.asarray(seg)
+    if case == "padded":
+        # few touched segments, each repeated, in a batch wider than the
+        # segment count: touched_segments pads its rows with segment 0
+        one = np.flatnonzero(seg == seg.max())
+        pick = np.concatenate([one[one < 512][:16], one[one >= 512][:8]])
+        pick = np.concatenate([pick, pick[:8], np.arange(8)])
+        q_hi, q_lo = q_hi[pick], q_lo[pick]
+        assert np.unique(seg[pick]).size < min(pick.size, cfg.max_segments)
+        return cfg, state, q_hi, q_lo, 128
+    if case == "overflow":
+        capacity = 128
+        assert np.bincount(seg).max() > capacity    # the cond fallback runs
+        return cfg, state, q_hi, q_lo, capacity
+    return cfg, state, q_hi, q_lo, 1024
+
+
+@pytest.mark.parametrize("case", ["no_fp", "overflow", "padded"])
+def test_fused_search_routed_matches_vmap(case):
+    """The TPU read program (touched-segment routing, -1 padding lanes,
+    capacity-overflow fallback) with the kernel in the Pallas interpreter
+    returns exactly what the per-key vmap path returns."""
+    cfg, state, q_hi, q_lo, capacity = _routed_case(case)
+    words = jnp.zeros((q_hi.shape[0], cfg.key_heap_words), jnp.uint32)
+    f_r, v_r = fused._fused_search_routed(cfg, "eh", state, q_hi, q_lo,
+                                          words, capacity, True)
+    f_v, v_v = engine.search_batch(cfg, "eh", state, q_hi, q_lo,
+                                   batching="vmap")
+    assert np.asarray(f_v).any() and not np.asarray(f_v).all()
+    assert (np.asarray(f_r) == np.asarray(f_v)).all()
+    assert (np.asarray(v_r) == np.asarray(v_v)).all()
 
 
 OPS = st.lists(st.sampled_from(["ins", "mask", "dup"]), min_size=1,
