@@ -64,15 +64,16 @@ def _stream(loaded: np.ndarray, fresh: np.ndarray, rng: np.random.Generator):
 def _drive(fe, loaded, fresh, rng):
     """Serve the stream chunk-by-chunk (closed loop: each round's ops are
     admitted together, the system drains before the next arrives — reads of
-    a round race exactly that round's storm). Returns wall seconds."""
+    a round race exactly that round's storm). Returns wall seconds and the
+    ops served, each stamped with its own latency."""
     t0 = time.perf_counter()
-    n_ops = 0
+    served = []
     for chunk in _stream(loaded, fresh, rng):
         for op in chunk:
             assert fe.submit(op)
-        n_ops += len(chunk)
+        served += chunk
         fe.drain()
-    return time.perf_counter() - t0, n_ops
+    return time.perf_counter() - t0, served
 
 
 def _lat_stats(lat_s):
@@ -110,11 +111,12 @@ def run():
         t = DashEH(CFG)
         t.insert(loaded, load_vals)
         fe = cls(t, max_batch=BATCH, queue_depth=1 << 16)
-        wall, n_ops = _drive(fe, loaded, fresh, np.random.default_rng(3))
-        stats = _lat_stats(fe.read_latencies)
-        stats["write_p99_us"] = _lat_stats(fe.write_latencies)["p99_us"]
+        wall, served = _drive(fe, loaded, fresh, np.random.default_rng(3))
+        stats = _lat_stats([op.latency for op in served if op.kind == READ])
+        stats["write_p99_us"] = _lat_stats(
+            [op.latency for op in served if op.kind != READ])["p99_us"]
         stats["wall_s"] = wall
-        stats["ops_per_s"] = n_ops / wall
+        stats["ops_per_s"] = len(served) / wall
         stats["splits"] = int(np.asarray(t.state.n_splits))
         if tag == "frontend":
             stats["snapshot_reads"] = fe.snapshot_reads
@@ -127,7 +129,6 @@ def run():
             pub = max(fes["published"], 1)
             stats["publish_bytes"] = fes["publish_bytes"]
             stats["publish_bytes_per_batch"] = fes["publish_bytes"] / pub
-            stats["publish_wall_s"] = fes["publish_seconds"]
             stats["planes_copied"] = fes["planes_copied"]
             stats["planes_aliased"] = fes["planes_aliased"]
             stats["hint_misses"] = fes["hint_misses"]
@@ -141,8 +142,7 @@ def run():
             # sojourn histograms must agree with the exact-sample
             # percentiles above within 10% — the bucket geometry bounds
             # the error at ±2.2%, so a miss means the frontend stopped
-            # feeding the histogram the same samples it keeps in
-            # read_latencies
+            # feeding the histogram the latencies its ops carry
             h = fe.obs.registry.get("frontend.read_sojourn_s").snapshot()
             stats["read_sojourn_hist"] = {
                 "n": h["n"], "p50_us": h["p50"] * 1e6,

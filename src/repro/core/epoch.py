@@ -61,7 +61,6 @@ from __future__ import annotations
 import functools
 import math
 import threading
-import time
 from collections import defaultdict
 from typing import Any, Callable, Optional
 
@@ -317,8 +316,9 @@ class SnapshotRegistry:
 
     Observability: ``publish_bytes`` / ``last_publish_bytes`` (bytes
     actually copied), ``planes_copied`` / ``planes_aliased`` (plane counts),
-    ``publish_seconds``, ``hint_misses`` (dirty segments the host tracker
-    failed to report — should stay 0), ``published`` / ``reclaimed``.
+    ``hint_misses`` (dirty segments the host tracker failed to report —
+    should stay 0), ``published`` / ``reclaimed``. The frontend's
+    ``publish`` span times a publish where it runs.
     """
 
     def __init__(self, epochs: Optional[EpochManager] = None,
@@ -336,7 +336,6 @@ class SnapshotRegistry:
         self.published = 0
         self.publish_bytes = 0
         self.last_publish_bytes = 0
-        self.publish_seconds = 0.0
         self.planes_copied = 0
         self.planes_aliased = 0
         self.hint_misses = 0
@@ -404,7 +403,6 @@ class SnapshotRegistry:
         import jax
         import jax.numpy as jnp
         assert self._pooled, "publish_cow needs the pool-managed registry"
-        t0 = time.perf_counter()
         force_full = (dirty_hint is not None and dirty_hint.full) \
             or cfg.pointer_mode
         prev = self.current                # stable: single publisher
@@ -424,7 +422,6 @@ class SnapshotRegistry:
         with self._lock:
             self.publish_bytes += nbytes
             self.last_publish_bytes = nbytes
-            self.publish_seconds += time.perf_counter() - t0
         if old is not None:
             self.epochs.retire(old)
         return snap
@@ -571,7 +568,6 @@ class SnapshotRegistry:
             "published": self.published,
             "publish_bytes": self.publish_bytes,
             "last_publish_bytes": self.last_publish_bytes,
-            "publish_seconds": self.publish_seconds,
             "planes_copied": self.planes_copied,
             "planes_aliased": self.planes_aliased,
             "reclaimed": self.reclaimed,

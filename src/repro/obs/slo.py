@@ -24,6 +24,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from .registry import Counter, Histogram, Registry
+from .trace import Tracer
 
 __all__ = ["SloRule", "SloMonitor"]
 
@@ -113,8 +114,10 @@ class SloMonitor:
     own (health string, limbo depth)."""
 
     def __init__(self, registry: Registry, rules=(), eval_interval: int = 64,
-                 clock=time.perf_counter):
+                 clock=time.perf_counter, tracer: Optional[Tracer] = None):
         self.registry = registry
+        # an evaluation tick is one ``slo.evaluate`` span
+        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
         self.rules = list(rules)
         self.eval_interval = max(1, int(eval_interval))
         self.clock = clock
@@ -163,7 +166,8 @@ class SloMonitor:
         self._ticks += 1
         if self._ticks % self.eval_interval:
             return None
-        return self.evaluate(extra() if callable(extra) else extra)
+        with self.tracer.span("slo.evaluate", "slo"):
+            return self.evaluate(extra() if callable(extra) else extra)
 
     def evaluate(self, extra: Optional[dict] = None) -> dict:
         now = self.clock()

@@ -20,9 +20,21 @@ journey as spans:
 Memory is bounded: closed spans land in a ring (``capacity`` entries, oldest
 dropped first, drops counted) and open spans are only ever the live stack +
 the handful of cross-tick spans the frontend holds. A disabled tracer
-(``enabled=False``, the default for production serving) is a few ``None``
-checks per call — the hot path stays cheap enough to leave call sites
-unconditional.
+(``enabled=False``, the default for production serving) records nothing:
+``span()`` hands back one shared no-op context and ``begin``/``instant``
+return None, so call sites stay unconditional.
+
+Profiler mirror: while enabled, every span (scoped, cross-tick and instant)
+also holds a ``jax.profiler.TraceAnnotation`` under its bare name from
+``begin`` to ``end``. Under a running profiler the span then lands on the
+host plane of the trace, on the clock of the device's ops, nested under
+whatever annotation was open when it began; its ``args`` stay in the ring.
+The binding accepts an exit out of nesting order, so a cross-tick span
+closes where it really ends. ``GcSpans`` records Python's collections as
+``gc`` spans: its hook annotates each collection on the profiler at once
+and leaves a record the tracer turns into a ring span at its next span
+boundary, since a collection can start inside any of the tracer's own
+calls.
 
 ``export_chrome_trace`` renders the ring as Chrome-trace JSON ("traceEvents"
 with complete/instant/flow events) for drop-into-``chrome://tracing`` /
@@ -34,17 +46,41 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
-__all__ = ["Span", "Tracer", "export_chrome_trace"]
+__all__ = ["GcSpans", "Span", "Tracer", "export_chrome_trace"]
+
+#: what a disabled tracer's ``span()`` returns: one shared context that
+#: yields None and records nothing
+_NO_SPAN = nullcontext()
+
+_TraceAnnotation = None          # jax.profiler.TraceAnnotation, on first use
+
+
+def _load_annotation():
+    """jax is imported on the first enabled span only, so reading a pool's
+    telemetry (obs/forensics.py) needs no jax."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+
+
+def _annotate(name: str):
+    """Open a profiler annotation (a no-op unless a profiler session is
+    running)."""
+    _load_annotation()
+    ann = _TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
     """One traced operation: half-open [t0, t1) plus causal edges."""
 
     __slots__ = ("sid", "parent", "name", "cat", "t0", "t1", "tid", "args",
-                 "links")
+                 "links", "ann")
 
     def __init__(self, sid: int, parent: Optional[int], name: str, cat: str,
                  t0: float, tid: int, args: Optional[dict]):
@@ -57,6 +93,7 @@ class Span:
         self.tid = tid
         self.args = args or {}
         self.links = []
+        self.ann = None                 # open profiler annotation
 
 
 class Tracer:
@@ -79,6 +116,7 @@ class Tracer:
         self.drop_counter = None        # registry counter: ring evictions
         #                                 surface as `trace.dropped` so
         #                                 silent telemetry loss is visible
+        self.gc = None                  # GcSpans whose records become spans
 
     # -- recording --------------------------------------------------------
 
@@ -93,6 +131,8 @@ class Tracer:
         with ``end``. Returns None when disabled."""
         if not self.enabled:
             return None
+        if self.gc is not None and self.gc.pending():
+            self._take_gc()
         if parent is None:
             cur = self.current()
             parent = cur.sid if cur is not None else None
@@ -100,6 +140,7 @@ class Tracer:
             parent = parent.sid
         sp = Span(self._next_sid, parent, name, cat, self.clock(), tid, args)
         self._next_sid += 1
+        sp.ann = _annotate(name)
         return sp
 
     def end(self, sp: Optional[Span], **args):
@@ -107,8 +148,21 @@ class Tracer:
         if sp is None:
             return
         self._close(sp, self.clock(), args)
+        if self.gc is not None and self.gc.pending():
+            self._take_gc()
+
+    def _take_gc(self):
+        """Close the collections ``self.gc`` recorded as ``gc`` spans."""
+        for gen, parent, t0, t1, collected in self.gc.take():
+            sp = Span(self._next_sid, parent, "gc", "runtime", t0, 0,
+                      {"generation": gen, "collected": collected})
+            self._next_sid += 1
+            self._close(sp, t1, {})
 
     def _close(self, sp: Span, t1: float, args: dict):
+        if sp.ann is not None:
+            sp.ann.__exit__(None, None, None)
+            sp.ann = None
         sp.t1 = t1
         if args:
             sp.args.update(args)
@@ -121,18 +175,21 @@ class Tracer:
         if self.sink is not None:
             self.sink.on_span(sp)
 
-    @contextmanager
     def span(self, name: str, cat: str = "", parent=None, **args):
         """Scoped child span: pushed on the stack so nested spans/instants
         parent to it automatically. Yields the Span (None when disabled)."""
+        if not self.enabled:
+            return _NO_SPAN
+        return self._scoped(name, cat, parent, args)
+
+    @contextmanager
+    def _scoped(self, name: str, cat: str, parent, args: dict):
         sp = self.begin(name, cat, parent=parent, **args)
-        if sp is not None:
-            self._stack.append(sp)
+        self._stack.append(sp)
         try:
             yield sp
         finally:
-            if sp is not None:
-                self._stack.pop()
+            self._stack.pop()
             self.end(sp)
 
     def instant(self, name: str, cat: str = "", parent=None, **args
@@ -160,6 +217,8 @@ class Tracer:
     # -- export -----------------------------------------------------------
 
     def spans(self) -> list:
+        if self.gc is not None and self.gc.pending():
+            self._take_gc()
         return list(self._ring)
 
     def clear(self):
@@ -175,6 +234,66 @@ class Tracer:
                 "trace_buffered": len(self._ring),
                 "trace_dropped": self.dropped,
                 "trace_capacity": self.capacity}
+
+
+class GcSpans:
+    """A ``gc.callbacks`` hook that records each of Python's collections
+    while its tracer is enabled. A collection can start at any allocation,
+    inside any of the tracer's or its sink's own calls, so the hook changes
+    no state but its own: it holds a profiler annotation ``gc`` across the
+    collection and writes (generation, innermost open span, t0, t1,
+    collected) into one of ``slots`` preallocated records. The tracer
+    closes those records as ``gc`` spans at its next ``begin``/``end``
+    (or ``spans()``); records overwritten before then count in ``lost``.
+    ``Observability`` installs one per bundle."""
+
+    def __init__(self, tracer: Tracer, slots: int = 64):
+        self.tracer = tracer
+        self._slots = [[0, None, 0.0, 0.0, 0] for _ in range(slots)]
+        self._written = 0               # collections recorded by the hook
+        self._taken = 0                 # of them handed to the tracer
+        self.lost = 0
+        self._ann = None                # the open collection's annotation
+        self._gen = 0
+        self._parent = None
+        self._t0 = 0.0
+        _load_annotation()              # no import inside a collection
+        tracer.gc = self
+
+    def __call__(self, phase: str, info: dict):
+        tr = self.tracer
+        if phase == "start":
+            if not tr.enabled:
+                return
+            stack = tr._stack
+            self._parent = stack[-1].sid if stack else None
+            self._gen = info["generation"]
+            self._ann = _annotate("gc")
+            self._t0 = tr.clock()
+        elif self._ann is not None:
+            t1 = tr.clock()
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+            slot = self._slots[self._written % len(self._slots)]
+            slot[0] = self._gen
+            slot[1] = self._parent
+            slot[2] = self._t0
+            slot[3] = t1
+            slot[4] = info["collected"]
+            self._written += 1
+
+    def pending(self) -> bool:
+        return self._taken != self._written
+
+    def take(self) -> list:
+        """The records written since the last take, oldest first."""
+        end = self._written
+        start = max(self._taken, end - len(self._slots))
+        self.lost += start - self._taken
+        out = [tuple(self._slots[i % len(self._slots)])
+               for i in range(start, end)]
+        self._taken = end
+        return out
 
 
 def export_chrome_trace(tracer: Tracer, path: Optional[str] = None,

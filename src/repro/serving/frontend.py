@@ -208,11 +208,9 @@ class FrontendBase:
         self.unflushed_publishes = 0  # publishes acked volatile while degraded
         self.snapshot_reads = 0      # queries answered from the snapshot
         self.retried_reads = 0       # queries re-run on the live version
-        self.read_latencies: List[float] = []
-        self.write_latencies: List[float] = []
         # observability bundle: metrics registry + tracer + SLO monitor
-        # (obs/). The sojourn histograms are fed by the same _finish_*
-        # stamps the latency lists come from — one clock, one code path.
+        # (obs/). The sojourn histograms are fed by the _finish_* stamps
+        # each Op carries (Op.latency) — one clock, one code path.
         self.obs = obs if obs is not None else obs_mod.Observability()
         # durable flight recorder (obs/blackbox.py): set by subclasses that
         # attach a pool — health transitions and write intents stamp it
@@ -303,31 +301,35 @@ class FrontendBase:
         return self.obs.snapshot()
 
     def _finish_reads(self, ops: List[Op], found, vals, n_changed: int):
-        now = self.obs.now()
-        for i, op in enumerate(ops):
-            op.found = bool(found[i])
-            op.result = int(vals[i])
-            op.status = INSERTED if op.found else NOT_FOUND
-            op.done_t = now
-            self.read_latencies.append(op.latency)
-        self._read_hist.observe_many([op.latency for op in ops])
-        self.snapshot_reads += len(ops) - n_changed
-        self.retried_reads += n_changed
+        with self.obs.tracer.span("read.finish", "serving"):
+            now = self.obs.now()
+            for i, op in enumerate(ops):
+                op.found = bool(found[i])
+                op.result = int(vals[i])
+                op.status = INSERTED if op.found else NOT_FOUND
+                op.done_t = now
+            self._read_hist.observe_many([op.latency for op in ops])
+            self.snapshot_reads += len(ops) - n_changed
+            self.retried_reads += n_changed
 
     def _finish_writes(self, ops: List[Op], statuses):
         now = self.obs.now()
         for op, st in zip(ops, statuses):
             op.status = int(st)
             op.done_t = now
-            self.write_latencies.append(op.latency)
         self._write_hist.observe_many([op.latency for op in ops])
 
     def step(self) -> bool:
-        """One tick: a read batch first (latency priority — it never waits
-        on the write side), then one write-side unit. Returns True if any
-        work ran."""
+        """One tick (a ``tick`` span): a read batch first (latency priority
+        — it never waits on the write side), then one write-side unit.
+        Returns True if any work ran."""
+        with self.obs.tracer.span("tick", "serving"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         did = False
-        read_ops = self.former.form(self.reads)
+        with self.obs.tracer.span("read.form", "serving"):
+            read_ops = self.former.form(self.reads)
         if read_ops:
             self._serve_reads(read_ops)
             did = True
@@ -498,7 +500,9 @@ class DashFrontend(FrontendBase):
         tr.end(ack)
 
     def _serve_reads_inner(self, ops: List[Op]) -> int:
-        hi, lo = _keys_arrays(ops, pad_to=self.former.max_batch)
+        tr = self.obs.tracer
+        with tr.span("read.keys", "serving"):
+            hi, lo = _keys_arrays(ops, pad_to=self.former.max_batch)
         if self.table.lazy_recovery:
             # lazy per-segment recovery hooks the READ path too (Sec. 4.8):
             # after a dirty restart the frontend serves immediately and the
@@ -506,34 +510,39 @@ class DashFrontend(FrontendBase):
             # retries the recovered buckets on the live version (recovery
             # bumps their version words), so results are never served from
             # unrecovered state. No-op (one np gather) on recovered tables.
-            before = self.table.recovered_segments
-            self.table._ensure_recovered(self.table._segments_of(
-                np.asarray(hi)[:len(ops)], np.asarray(lo)[:len(ops)]))
-            if self.table.recovered_segments != before:
-                self._dirty = True
+            with tr.span("read.recover", "recovery"):
+                before = self.table.recovered_segments
+                self.table._ensure_recovered(self.table._segments_of(
+                    np.asarray(hi)[:len(ops)], np.asarray(lo)[:len(ops)]))
+                if self.table.recovered_segments != before:
+                    self._dirty = True
         with self.registry.acquire() as snap:
-            found, vals = dash_engine.search_batch(
-                self.cfg, self.mode, snap.state, hi, lo,
-                batching=self.read_batching)
-            found, vals = np.asarray(found).copy(), np.asarray(vals).copy()
+            with tr.span("read.dispatch", "serving"):
+                found, vals = dash_engine.search_batch(
+                    self.cfg, self.mode, snap.state, hi, lo,
+                    batching=self.read_batching)
+            with tr.span("read.wait", "serving"):
+                found = np.asarray(found).copy()
+                vals = np.asarray(vals).copy()
             n_changed = 0
             if self._dirty:
                 # verify only when the live state diverged since publish
                 # (a clean snapshot is the live state by construction)
-                changed = np.asarray(buckets_changed(
-                    self.cfg, self.mode, snap.state, self.table.state,
-                    hi, lo)).copy()
-                changed[len(ops):] = False        # padding lanes never retry
-                n_changed = int(changed.sum())
-            if n_changed:
-                # lazy retry: one extra dispatch ONLY when the verify pass
-                # flagged queries — this is the only read-path dependency on
-                # in-flight writes/SMOs
-                f2, v2 = dash_engine.search_batch(
-                    self.cfg, self.mode, self.table.state, hi, lo,
-                    batching=self.read_batching)
-                found[changed] = np.asarray(f2)[changed]
-                vals[changed] = np.asarray(v2)[changed]
+                with tr.span("read.verify", "serving"):
+                    changed = np.asarray(buckets_changed(
+                        self.cfg, self.mode, snap.state, self.table.state,
+                        hi, lo)).copy()
+                    changed[len(ops):] = False    # padding lanes never retry
+                    n_changed = int(changed.sum())
+                    if n_changed:
+                        # lazy retry: one extra dispatch ONLY when the
+                        # verify pass flagged queries — this is the only
+                        # read-path dependency on in-flight writes/SMOs
+                        f2, v2 = dash_engine.search_batch(
+                            self.cfg, self.mode, self.table.state, hi, lo,
+                            batching=self.read_batching)
+                        found[changed] = np.asarray(f2)[changed]
+                        vals[changed] = np.asarray(v2)[changed]
         self._finish_reads(ops, found, vals, n_changed)
         return n_changed
 
@@ -728,8 +737,8 @@ class DashFrontend(FrontendBase):
                                                self.recorder))
         return out
 
-    def step(self) -> bool:
-        did = super().step()
+    def _tick(self) -> bool:
+        did = super()._tick()
         if self.scrubber is not None:
             self._scrub_countdown -= 1
             if self._scrub_countdown <= 0:

@@ -2,6 +2,7 @@
 op-lifecycle span causality across publish/flush/SMO, bounded trace
 memory, Chrome-trace export schema, SLO windows + rules, and the one-clock
 sojourn unification in the serving frontend."""
+import gc
 import json
 import math
 
@@ -12,8 +13,8 @@ from repro import obs as obs_mod
 from repro import persist
 from repro.core import DashConfig
 from repro.core.table import DashEH
-from repro.obs import (Histogram, Observability, Registry, SloRule, Tracer,
-                       export_chrome_trace)
+from repro.obs import (FlightRecorder, Histogram, Observability, Registry,
+                       SloRule, Tracer, export_chrome_trace)
 from repro.persist.chaos import CHAOS_CFG
 from repro.serving import frontend as fe
 from repro.serving.frontend import INSERT, READ, DashFrontend, Op
@@ -158,6 +159,133 @@ def test_tracer_nesting_and_links():
     assert set(ack.links) == {out.sid, det.sid}
 
 
+def test_disabled_span_is_one_shared_noop_context():
+    tr = Tracer(enabled=False)
+    a, b = tr.span("x"), tr.span("y", "t", n=3)
+    assert a is b                                   # nothing built per call
+    with a as sp:
+        assert sp is None and tr.current() is None
+    assert tr.spans() == [] and tr.recorded == 0
+
+
+def test_enabled_spans_mirror_into_the_profiler(monkeypatch):
+    from repro.obs import trace as trace_mod
+    log = []
+
+    class Annotation:                               # takes no kwargs
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_mod, "_TraceAnnotation", Annotation)
+    tr = Tracer(enabled=True)
+    with tr.span("outer", "t", n=7):
+        with tr.span("inner"):
+            pass
+        tr.instant("mark")
+        cross = tr.begin("cross")
+    tr.end(cross)                           # closes out of nesting order
+    assert log == [("enter", "outer"), ("enter", "inner"),
+                   ("exit", "inner"), ("enter", "mark"), ("exit", "mark"),
+                   ("enter", "cross"), ("exit", "outer"), ("exit", "cross")]
+    by_name = {sp.name: sp for sp in tr.spans()}
+    assert by_name["outer"].args == {"n": 7}        # args stay in the ring
+    assert all(sp.ann is None for sp in tr.spans())
+    log.clear()
+    Tracer(enabled=False).span("off").__enter__()
+    assert log == []
+
+
+def _hooks(hook):
+    return sum(cb is hook for cb in gc.callbacks)
+
+
+def test_gc_spans_and_the_hook_leaves_on_close():
+    obs = Observability(trace=True)
+    hook = obs._gc
+    assert _hooks(hook) == 1
+    for _ in range(2):            # frontends sharing a bundle share its hook
+        DashFrontend(DashEH(CFG), obs=obs)
+    assert _hooks(hook) == 1
+    with obs.tracer.span("outer") as out:
+        gc.collect()
+    full = [sp for sp in obs.tracer.spans() if sp.name == "gc"
+            and sp.parent == out.sid and sp.args["generation"] == 2]
+    assert full and full[-1].args["collected"] >= 0
+    assert out.t0 <= full[-1].t0 <= full[-1].t1 <= out.t1
+    assert full[-1].cat == "runtime"
+    obs.close()
+    assert _hooks(hook) == 0 and not obs.trace
+    recorded = obs.tracer.recorded
+    gc.collect()
+    assert obs.tracer.recorded == recorded
+    obs.trace = True                                # the one switch, again
+    assert _hooks(hook) == 1
+    obs.trace = False
+    assert _hooks(hook) == 0
+    dropped = Observability(trace=True)
+    hook = dropped._gc
+    del dropped                                     # never closed
+    gc.collect()
+    assert _hooks(hook) == 0
+    off = Observability(trace=False)
+    assert off._gc is None
+
+
+def test_gc_hook_changes_no_tracer_state_and_overflow_is_counted():
+    tr = Tracer(enabled=True)
+    hook = obs_mod.GcSpans(tr, slots=2)
+    with tr.span("outer") as out:
+        for gen in range(3):                # what gc.callbacks would call
+            hook("start", {"generation": gen})
+            hook("stop", {"generation": gen, "collected": gen})
+        assert tr.recorded == 0 and tr._next_sid == out.sid + 1
+    gcs = [sp for sp in tr.spans() if sp.name == "gc"]
+    assert [sp.args["generation"] for sp in gcs] == [1, 2] and hook.lost == 1
+    assert all(sp.parent == out.sid for sp in gcs)
+    assert len({sp.sid for sp in tr.spans()}) == len(tr.spans()) == 3
+
+
+def test_traced_durable_frontend_survives_collections_everywhere(tmp_path):
+    """Collections inside the tracer's and the flight recorder's own calls
+    (their clocks run one, and the thresholds are low) through several
+    flushes of a recorder kept over budget: no flush raises, span ids stay
+    unique, and every write is acknowledged."""
+    def collecting_clock():
+        gc.collect(0)
+        return obs_mod.now()
+
+    t = persist.create(str(tmp_path / "t.pool"), CHAOS_CFG)
+    t.writeback.attach_recorder(FlightRecorder(capacity_bytes=8192,
+                                               clock=collecting_clock))
+    obs = Observability(trace=True, trace_capacity=1 << 20)
+    obs.tracer.clock = collecting_clock
+    f = DashFrontend(t, obs=obs)
+    keys = unique_keys(np.random.default_rng(12), 240)
+    writes = [Op(INSERT, int(k), int(k & 0x7FFFFFFF)) for k in keys]
+    thresholds = gc.get_threshold()
+    gc.set_threshold(5, 50, 1000)
+    try:
+        for i in range(0, len(writes), 60):
+            for op in writes[i:i + 60]:
+                assert f.submit(op)
+            f.drain()
+    finally:
+        gc.set_threshold(*thresholds)
+        obs.close()
+    assert all(op.status == fe.INSERTED for op in writes)
+    assert f.recorder.evicted > 0 and f.recorder.windows_written >= 4
+    spans = obs.tracer.spans()
+    assert len({sp.sid for sp in spans}) == len(spans)
+    assert sum(sp.name == "gc" for sp in spans) > 0
+    assert sum(sp.name == "flush" for sp in spans) >= 4
+
+
 # ---------------------------------------------------------------------------
 # chrome trace export schema
 # ---------------------------------------------------------------------------
@@ -275,23 +403,66 @@ def test_frontend_sojourn_unified_through_obs_clock():
     t = DashEH(CFG)
     f = DashFrontend(t)
     keys = unique_keys(np.random.default_rng(5), 600)
-    for k in keys:
-        f.submit(Op(INSERT, int(k), int(k & 0x7FFFFFFF)))
-    for k in keys[:200]:
-        f.submit(Op(READ, int(k)))
+    writes = [Op(INSERT, int(k), int(k & 0x7FFFFFFF)) for k in keys]
+    reads = [Op(READ, int(k)) for k in keys[:200]]
+    for op in writes + reads:
+        f.submit(op)
     f.drain()
     # every completed op went through obs.now() twice; the registry
-    # histograms saw exactly the same samples the latency lists keep
+    # histograms saw exactly the latencies the ops themselves carry
     rh = f.obs.registry.get("frontend.read_sojourn_s")
     wh = f.obs.registry.get("frontend.write_sojourn_s")
-    assert rh.n == len(f.read_latencies) == 200
-    assert wh.n == len(f.write_latencies) == 600
-    assert rh.total == pytest.approx(sum(f.read_latencies))
-    assert wh.vmax == max(f.write_latencies)
+    assert rh.n == len(reads) == 200
+    assert wh.n == len(writes) == 600
+    assert rh.total == pytest.approx(sum(op.latency for op in reads))
+    assert wh.vmax == max(op.latency for op in writes)
     snap = f.obs_snapshot()
     assert snap["metrics"]["stats.published"] == f.stats()["published"]
     assert snap["slo"]["tick"] > 0
     assert "read_sojourn" in snap["slo"]
+
+
+def test_frontend_read_tick_spans():
+    obs = Observability(trace=True)
+    f = DashFrontend(DashEH(CFG), obs=obs)
+    keys = unique_keys(np.random.default_rng(6), 300)
+    for k in keys:
+        f.submit(Op(INSERT, int(k), 1))
+    f.drain()
+    obs.tracer.clear()
+    for k in keys:
+        f.submit(Op(READ, int(k)))
+    steps = 0
+    while f.busy:
+        f.step()
+        steps += 1
+    obs.close()
+    spans = [sp for sp in obs.tracer.spans() if sp.name != "gc"]
+    by_sid = {sp.sid: sp for sp in spans}
+    ticks = [sp for sp in spans if sp.name == "tick"]
+    batches = [sp for sp in spans if sp.name == "read_batch"]
+    assert len(ticks) == steps == 2 and len(batches) == 2
+    assert sum(sp.args["n"] for sp in batches) == 300
+    for sp in spans:
+        if sp.name in ("read.form", "read_batch"):
+            assert by_sid[sp.parent].name == "tick", sp.name
+        elif sp.name.startswith("read."):
+            assert by_sid[sp.parent].name == "read_batch", sp.name
+            assert by_sid[sp.parent].t0 <= sp.t0 <= sp.t1 \
+                <= by_sid[sp.parent].t1
+    stages = {sp.name for sp in spans}
+    assert {"read.form", "read.keys", "read.recover", "read.dispatch",
+            "read.wait", "read.finish"} <= stages
+    assert "read.verify" not in stages          # nothing written since
+
+
+def test_slo_evaluation_is_a_span():
+    obs = Observability(trace=True, slo_interval=3)
+    for _ in range(7):
+        obs.slo.tick()
+    obs.close()
+    assert [sp.name for sp in obs.tracer.spans()
+            if sp.name != "gc"] == ["slo.evaluate"] * 2
 
 
 def test_frontend_slo_extra_and_stats_fields():
