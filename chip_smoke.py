@@ -236,15 +236,15 @@ def read_programs_hold_kernels(table, small_batch: int, check_batch: int):
 
     def q(n):
         z = jnp.zeros((n,), jnp.uint32)
-        return z, z, jnp.zeros((n, cfg.key_heap_words), jnp.uint32)
+        return z, z
 
-    fused_fn, routed_fn = fused._fused_search_routed, engine._search_batch_routed
+    fused_fn, routed_fn = fused._fused_search_kernel, engine._search_batch_routed
     return {
         "fused": fused_fn._cache_size() > 0 and kernel_in_program(
-            fused_fn, cfg, "eh", table.state, *q(small_batch), small_batch,
-            False),
+            fused_fn, cfg, "eh", table.state, *q(small_batch), False),
         "routed": routed_fn._cache_size() > 0 and kernel_in_program(
-            routed_fn, cfg, "eh", table.state, *q(check_batch), 128),
+            routed_fn, cfg, "eh", table.state, *q(check_batch),
+            jnp.zeros((check_batch, cfg.key_heap_words), jnp.uint32), 128),
     }
 
 

@@ -578,12 +578,11 @@ def search_batch(cfg: DashConfig, mode: str, state: DashState,
     (``kernels/ops.py:probe_direct``) — same fingerprint-first read
     discipline, no per-segment lane planes (those are the TPU VMEM
     blocking)."""
-    if words is None:
-        words = _dummy_words(cfg, keys_hi.shape[0])
     if batching == "fused":
         from repro.kernels import fused
-        return fused.fused_search(cfg, mode, state, keys_hi, keys_lo, words,
-                                  capacity)
+        return fused.fused_search(cfg, mode, state, keys_hi, keys_lo, words)
+    if words is None:
+        words = _dummy_words(cfg, keys_hi.shape[0])
     if batching == "pallas" and not pallas_search_eligible(cfg):
         batching = "vmap"      # fingerprint path would silently miss records
     if batching == "auto":

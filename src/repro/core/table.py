@@ -223,15 +223,15 @@ class DashTable:
         """(batching, capacity) for a read batch: the fused single-dispatch
         path for small batches (its whole point is killing per-stage launch
         overhead), the Pallas fingerprint path for large batches on eligible
-        configs, per-key vmap otherwise. Both routed kernels get the exact
-        per-segment lane capacity, so no lane overflows to the per-key path."""
+        configs, per-key vmap otherwise. The routed fingerprint kernel gets
+        the exact per-segment lane capacity, so no lane overflows to the
+        per-key path; the fused path has no lanes to size."""
         from repro.kernels import fused    # local: kernels import core
-        capacity = self._pow2(self._max_per_segment(seg), floor=128)
         if (seg.size <= self.fused_threshold
                 and fused.fused_search_eligible(self.cfg)):
-            return "fused", capacity
+            return "fused", None
         if seg.size >= 256 and engine.pallas_search_eligible(self.cfg):
-            return "pallas", capacity
+            return "pallas", self._pow2(self._max_per_segment(seg), floor=128)
         return "vmap", None
 
     def _ensure_recovered(self, touched: np.ndarray):
@@ -334,7 +334,17 @@ class DashTable:
         found, vals = engine.search_batch(self.cfg, self.mode, self.state,
                                           hi, lo, w, batching=batching,
                                           capacity=capacity)
+        self.count_read_batch(batching)
         return np.asarray(found), np.asarray(vals)
+
+    def count_read_batch(self, batching: str):
+        """Count a fused read batch in the obs registry by the program it
+        ran: ``reads.fused_kernel_batches`` (the per-query Pallas kernel)
+        or ``reads.fused_direct_batches`` (the direct jnp lowering)."""
+        if self.obs is not None and batching == "fused":
+            from repro.kernels import fused    # local: kernels import core
+            path = fused.fused_read_path(self.cfg)
+            self.obs.registry.counter(f"reads.fused_{path}_batches").inc()
 
     def delete(self, keys=None, words=None):
         hi, lo, w = self._prep(keys, words)

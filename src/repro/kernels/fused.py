@@ -13,16 +13,17 @@ hashing at low concurrency: per-op overhead governs latency.
 Two entry points, both single-dispatch:
 
 ``fused_search``
-    Reads. On TPU: ``fused_probe`` — one Pallas mega-kernel whose grid walks
-    the segments the batch actually touches; each program fuses the one-hot
-    MXU bucket gather (the route), the fingerprint compare (the probe), the
-    16-bit-half key compare (the verify) and the value select, for the
-    target bucket, the probing bucket and the stash rows. Pallas's grid
-    pipeline double-buffers the next segment's plane block into VMEM while
-    the current one computes. On non-TPU hosts: a direct-addressed jnp
-    lowering — a single gather of the (window + stash) candidate rows per
-    query and one dense compare, no lane planes at all (those only pay off
-    as TPU VMEM blocking).
+    Reads. On TPU: ``fused_probe`` — one Pallas kernel with a grid step per
+    query. XLA hashes the batch and reads each query's directory word; the
+    kernel gets every query's segment and bucket as prefetched scalars and
+    DMAs only the (slots, 8 buckets, 128 segments) tiles of the planes that
+    hold the query's target bucket, probing bucket and stash rows, in the
+    planes' stored (segment-minor) layout, so nothing the size of the table
+    is copied. It compares fingerprint byte, allocation bit and both key
+    words and writes the first matching slot's value at the query's own
+    position. On non-TPU hosts: a direct-addressed jnp lowering — a single
+    gather of the (window + stash) candidate rows per query and one dense
+    compare.
 
 ``fused_insert``
     Writes. One jitted program: segment routing (``ops.route_writes``), the
@@ -51,6 +52,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bucket as bk
 from repro.core import hashing, layout
@@ -59,8 +61,14 @@ from repro.core.layout import (DROPPED, EXISTS, INSERTED, NEED_SPLIT,
 
 I32 = jnp.int32
 
-BQ = 128          # queries per kernel program (full VPU/MXU row block)
-ROWS = 128        # padded bucket rows per segment plane
+# the read kernel's tiles: GB bucket rows (a u32 sublane tile) by SEG_TILE
+# segments (a lane tile); NO_MATCH is the priority of a non-candidate
+GB = 8
+SEG_TILE = 128
+NO_MATCH = 1 << 30
+# queries per kernel call: its prefetched scalars, six words a query, must
+# fit the 1 MiB of scalar memory
+MAX_QUERIES = 16384
 
 
 # ---------------------------------------------------------------------------
@@ -75,13 +83,12 @@ def fused_search_eligible(cfg: DashConfig) -> bool:
 
 
 def fused_kernel_eligible(cfg: DashConfig) -> bool:
-    """Configs the Pallas mega-kernel spans: inline keys and a 2-bucket
-    window (balanced pairs, or probe_len <= 2), planes within the padded
-    tile. Fingerprints may be off — the wrapper feeds zero fp planes and
-    zero query bytes so the compare degenerates to the allocated mask."""
-    return (not cfg.pointer_mode
-            and (cfg.use_balanced or cfg.probe_len <= 2)
-            and cfg.buckets_total <= ROWS)
+    """Configs the per-query Pallas kernel spans: inline keys, a window of
+    at most two buckets (balanced pairs, or probe_len <= 2), normal buckets
+    that fill whole tiles of ``GB`` rows and stash rows that fit the next
+    tile. Fingerprints may be off — the kernel then compares no fp byte."""
+    return (not cfg.pointer_mode and cfg.probe_window <= 2
+            and cfg.num_buckets % GB == 0 and cfg.num_stash <= GB)
 
 
 def fused_insert_eligible(cfg: DashConfig) -> bool:
@@ -166,232 +173,230 @@ def _fused_search_direct(cfg: DashConfig, mode: str, state: DashState,
 
 
 # ---------------------------------------------------------------------------
-# fused read — the Pallas mega-kernel (TPU path; interpret mode in tests)
+# fused read — the per-query Pallas kernel (TPU path; interpret mode in tests)
 # ---------------------------------------------------------------------------
 
-# A touched segment's plane, slot-major: feature k of slot j sits in row
-# k * SROWS + j, bucket row r in column r. The eight features are the
-# fingerprint byte, the allocation bit, and the 16-bit halves of key_hi,
-# key_lo and the value, so one (FEATS, ROWS) @ (ROWS, BQ) one-hot matmul
-# gathers everything a query compares against in its bucket.
-SROWS = 16                        # slot rows per feature (14 real -> 16)
-F_FP, F_ALLOC, F_KHIA, F_KHIB, F_KLOA, F_KLOB, F_VA, F_VB = range(8)
-FEATS = 8 * SROWS                 # = 128, one MXU tile
+def stored_views(state: DashState, use_fingerprints: bool):
+    """The record planes as the device stores them: segments minor.
+
+    XLA keeps a large table's (S, BT, SL) planes segment-minor (the slot
+    axis is far narrower than a lane tile), so these transposes are
+    bitcasts there and the kernel reads the planes where they lie. Where
+    the layout differs they cost a copy and stay correct. Returns
+    (key_hi, key_lo, val) as (SL, BT, S), fp as (BT, 16, S) — None with
+    fingerprints off — and meta as (BT, S), in the state's dtypes."""
+    fp = state.fp.transpose(1, 2, 0) if use_fingerprints else None
+    return (state.key_hi.transpose(2, 1, 0), state.key_lo.transpose(2, 1, 0),
+            state.val.transpose(2, 1, 0), fp, state.meta.T)
 
 
-def _halves(x):
-    """Split a uint32 plane into (lo16, hi16) int32 halves — exact in f32."""
-    xi = x.astype(jnp.uint32)
-    return ((xi & U32(0xFFFF)).astype(jnp.int32),
-            (xi >> U32(16)).astype(jnp.int32))
+def _tiles(nb: int, ns: int, window: int):
+    """The bucket-row tiles a query reads: its target bucket's, its probing
+    bucket's where that can lie in another tile, and the stash rows'."""
+    return (["home"] + (["probe"] if window == 2 and nb > GB else [])
+            + (["stash"] if ns else []))
 
 
-def _fused_read_block(plane_ref, qfp_ref, qb_ref, qpb_ref, qhia_ref,
-                      qhib_ref, qloa_ref, qlob_ref, found_ref, val_ref, *,
-                      nb: int, ns: int):
-    """One (touched-segment, query-block) program, queries on the lanes:
-    one one-hot MXU gather per probed row (target bucket, probing bucket,
-    then each stash row) pulls every feature of that row, the keys are
-    verified in 16-bit halves, and the first matching slot's value is
-    selected. The fp32-precision matmul keeps the 16-bit halves exact."""
-    plane = plane_ref[0].astype(jnp.float32)               # (FEATS, ROWS)
-    qfp = qfp_ref[0]                                       # (1, BQ) each
-    q = [r[0] for r in (qhia_ref, qhib_ref, qloa_ref, qlob_ref)]
-    qb = qb_ref[0]
-    live = qb >= 0
-    rows = jax.lax.broadcasted_iota(jnp.int32, (ROWS, BQ), 0)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (SROWS, BQ), 0)
-
-    def row_hits(qrow):
-        onehot = (rows == qrow).astype(jnp.float32)        # (ROWS, BQ)
-        g = jnp.dot(plane, onehot, precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
-
-        def f(k):
-            return g[k * SROWS:(k + 1) * SROWS]            # (SROWS, BQ)
-
-        eq = ((f(F_ALLOC) == 1) & (f(F_FP) == qfp)
-              & (f(F_KHIA) == q[0]) & (f(F_KHIB) == q[1])
-              & (f(F_KLOA) == q[2]) & (f(F_KLOB) == q[3]) & live)
-        first = jnp.min(jnp.where(eq, slot, SROWS), axis=0, keepdims=True)
-        val = f(F_VA) | (f(F_VB) << 16)
-        return first < SROWS, jnp.sum(jnp.where(slot == first, val, 0),
-                                      axis=0, keepdims=True)
-
-    found, val = row_hits(qb)
-    for qrow in [qpb_ref[0]] + [jnp.full_like(qb, nb + s) for s in range(ns)]:
-        ok, v = row_hits(qrow)
-        val = jnp.where(ok & ~found, v, val)
-        found = found | ok
-    found_ref[0] = found.astype(jnp.int32)
-    val_ref[0] = val
+def _tile_of(kind: str, b, nb: int):
+    if kind == "home":
+        return b // GB
+    if kind == "probe":
+        return ((b + 1) & (nb - 1)) // GB
+    return nb // GB
 
 
-def fused_plane_views(cfg: DashConfig, state: DashState, segments):
-    """(U, FEATS, ROWS) int32 slot-major planes of the given segments only.
+def _probe_query(seg_ref, b_ref, hi_ref, lo_ref, qfp_ref, act_ref, *refs,
+                 nb: int, ns: int, window: int, use_fp: bool):
+    """One grid step = one query. Each of the step's tiles holds eight
+    bucket rows of 128 segments; the query's candidates are single rows of
+    them, and its segment one lane. Every candidate (row, slot) gets a
+    priority — target bucket, probing bucket, then stash rows, then the
+    slot: ``probe_in_segment``'s visit order and ``bucket_probe``'s
+    first-slot rule — and the least wins. The result lands at the query's
+    own position of the resident outputs."""
+    found_ref, val_ref = refs[-2:]
+    per = 5 if use_fp else 4
+    tiles = {kind: refs[k * per:(k + 1) * per]
+             for k, kind in enumerate(_tiles(nb, ns, window))}
+    i = pl.program_id(0)
+    s, b = seg_ref[i], b_ref[i]
+    pb = (b + 1) & (nb - 1)
+    cross = pb // GB != b // GB          # the probing bucket's own tile
+    sl, _, sb = refs[0].shape
+    slot = jax.lax.broadcasted_iota(I32, (sl, sb), 0)
+    at_lane = jax.lax.broadcasted_iota(I32, (sl, sb), 1) == s % sb
 
-    ``segments``: (U,) int32 segment ids (may repeat for padding). Stash
-    rows beyond each segment's ``stash_active`` get a zero alloc bit so the
-    kernel needs no activation logic. With fingerprints disabled the fp
-    feature is zero (queries feed zero bytes -> compare is a no-op)."""
-    BT, ns, NB, SL = (cfg.buckets_total, cfg.num_stash, cfg.num_buckets,
-                      cfg.num_slots)
-    alloc = layout.meta_alloc(state.meta[segments])                  # (U, BT)
-    if ns:
-        srow = jnp.arange(BT) - NB                           # stash index or <0
-        act = state.stash_active[segments][:, None]
-        alloc = jnp.where((srow[None, :] >= 0) & (srow[None, :] >= act),
-                          U32(0), alloc)
-    abits = ((alloc[..., None] >> jnp.arange(SL, dtype=U32)) & U32(1))
-    fp = state.fp[segments][..., :SL].astype(jnp.int32)
-    if not cfg.use_fingerprints:
-        fp = jnp.zeros_like(fp)
-    feats = ((fp, abits.astype(jnp.int32)) + _halves(state.key_hi[segments])
-             + _halves(state.key_lo[segments]) + _halves(state.val[segments]))
-    x = jnp.stack(feats, axis=1)                             # (U, 8, BT, SL)
-    x = jnp.pad(x, ((0, 0), (0, 0), (0, ROWS - BT), (0, SROWS - SL)))
-    return x.transpose(0, 1, 3, 2).reshape(x.shape[0], FEATS, ROWS)
+    # (tile, row within it, rank, whether the row is a candidate)
+    rows = [(tiles["home"], b % GB, 0, True)]
+    if window == 2:
+        rows.append((tiles["home"], pb % GB, 1, ~cross))
+        if "probe" in tiles:
+            rows.append((tiles["probe"], pb % GB, 1, cross))
+    rows += [(tiles["stash"], k, 2 + k, k < act_ref[i]) for k in range(ns)]
+
+    prios, vals = [], []
+    for tile, r, rank, live in rows:
+        khi, klo, val = (jax.lax.bitcast_convert_type(t[:, r, :], I32)
+                         for t in tile[:3])                   # (SL, sb)
+        meta = jax.lax.bitcast_convert_type(tile[3][pl.ds(r, 1), :], I32)
+        hit = (at_lane & live & (((meta >> slot) & 1) == 1)
+               & (khi == hi_ref[i]) & (klo == lo_ref[i]))
+        if use_fp:
+            hit &= tile[4][r].astype(I32)[:sl] == qfp_ref[i]
+        prios.append(jnp.where(hit, rank * 16 + slot, NO_MATCH))
+        vals.append(val)
+
+    best = functools.reduce(jnp.minimum, [jnp.min(p) for p in prios])
+    value = functools.reduce(jnp.add, [jnp.sum(jnp.where(p == best, v, 0))
+                                       for p, v in zip(prios, vals)])
+    shape = found_ref.shape
+    pos = (jax.lax.broadcasted_iota(I32, shape, 0) * shape[1]
+           + jax.lax.broadcasted_iota(I32, shape, 1)) == i
+
+    @pl.when(i == 0)
+    def _():
+        found_ref[...] = jnp.zeros(shape, I32)
+        val_ref[...] = jnp.zeros(shape, I32)
+
+    found = best < NO_MATCH
+    found_ref[...] = jnp.where(pos, found.astype(I32), found_ref[...])
+    val_ref[...] = jnp.where(pos, jnp.where(found, value, 0), val_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("nb", "ns", "interpret"))
-def fused_probe(planes, q_fp, q_b, q_pb, q_hi, q_lo, *, nb: int, ns: int,
-                interpret: bool):
-    """The mega-kernel: route+probe+verify over compact touched segments.
+@functools.partial(jax.jit, static_argnames=("nb", "ns", "window",
+                                             "interpret"))
+def fused_probe(planes, seg, b, q_hi, q_lo, q_fp, active, *, nb: int,
+                ns: int, window: int, interpret: bool):
+    """The read kernel: one grid step per query, planes read in place.
 
     Args:
-      planes: output of ``fused_plane_views`` — (U, FEATS, ROWS).
-      q_fp, q_b, q_pb: (U, C) int32 routed fingerprint bytes and bucket
-        rows (-1 = padding lane).
-      q_hi, q_lo: (U, C) uint32 routed key words.
+      planes: ``stored_views`` of the table.
+      seg, b: (n,) int32 segment and target bucket of each query.
+      q_hi, q_lo: (n,) uint32 key words; q_fp: (n,) int32 fingerprint
+        bytes; active: (n,) int32 active stash rows of each query's segment.
       interpret: run the Pallas interpreter (CPU tests) instead of Mosaic.
 
-    Returns (found, val): (U, C) int32 / uint32 per-lane results. The grid
-    is (U, C // BQ) with per-segment plane blocks: Pallas's sequential grid
-    pipeline prefetches segment u+1's block while u computes — the
-    double-buffering this path is named for. Query rows travel as (U, 1, C)
-    so every block satisfies the TPU's (8, 128) tiling rule.
+    Returns (found, val): (n,) int32 / uint32, in query order. The queries'
+    segments and buckets are prefetched scalars, and every step's block
+    indices come from them, so each step DMAs only the (SL, 8, 128) tiles
+    that hold its query's rows; Pallas skips the copy where a step's tile
+    is the previous step's.
     """
-    U, C = q_fp.shape
-    assert C % BQ == 0
-    rows3 = [x.reshape(U, 1, C)
-             for x in (q_fp, q_b, q_pb) + _halves(q_hi) + _halves(q_lo)]
-    grid = (U, C // BQ)
-    pspec = pl.BlockSpec((1, FEATS, ROWS), lambda s, c: (s, 0, 0))
-    qspec = pl.BlockSpec((1, 1, BQ), lambda s, c: (s, 0, c))
-    out_i32 = jax.ShapeDtypeStruct((U, 1, C), jnp.int32)
-    found, val = pl.pallas_call(
-        functools.partial(_fused_read_block, nb=nb, ns=ns),
-        grid=grid,
-        in_specs=[pspec] + [qspec] * 7,
-        out_specs=[qspec, qspec],
+    khi, klo, val, fp, meta = planes
+    use_fp = fp is not None
+    sl, _, S = khi.shape
+    sb = SEG_TILE if S % SEG_TILE == 0 else S
+    n = seg.shape[0]
+    in_specs, operands = [], []
+    for kind in _tiles(nb, ns, window):
+        def tile(i, seg, b, *_, kind=kind):
+            return _tile_of(kind, b[i], nb), seg[i] // sb
+
+        in_specs += [pl.BlockSpec((sl, GB, sb),
+                                  lambda *a, tile=tile: (0, *tile(*a)))] * 3
+        in_specs.append(pl.BlockSpec((GB, sb), tile))
+        operands += [khi, klo, val, meta]
+        if use_fp:
+            in_specs.append(pl.BlockSpec(
+                (GB, fp.shape[1], sb),
+                lambda *a, tile=tile: (tile(*a)[0], 0, tile(*a)[1])))
+            operands.append(fp)
+    out = pl.BlockSpec((pl.cdiv(n, 128), 128), lambda i, *_: (0, 0))
+    out_i32 = jax.ShapeDtypeStruct((pl.cdiv(n, 128), 128), I32)
+    scalars = [x.astype(I32) for x in (seg, b)]
+    scalars += [jax.lax.bitcast_convert_type(x, I32) for x in (q_hi, q_lo)]
+    scalars += [q_fp.astype(I32), active.astype(I32)]
+    found, value = pl.pallas_call(
+        functools.partial(_probe_query, nb=nb, ns=ns, window=window,
+                          use_fp=use_fp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(n,), in_specs=in_specs,
+            out_specs=[out, out]),
         out_shape=[out_i32, out_i32],
         interpret=interpret,
-    )(planes, *rows3)
-    return found.reshape(U, C), val.reshape(U, C).astype(U32)
+    )(*scalars, *operands)
+    return (found.reshape(-1)[:n],
+            jax.lax.bitcast_convert_type(value.reshape(-1)[:n], U32))
 
 
-@functools.partial(jax.jit, static_argnames=("nb", "ns"))
-def fused_probe_jnp(planes, q_fp, q_b, q_pb, q_hi, q_lo, *, nb: int, ns: int):
+@functools.partial(jax.jit, static_argnames=("nb", "ns", "window"))
+def fused_probe_jnp(planes, seg, b, q_hi, q_lo, q_fp, active, *, nb: int,
+                    ns: int, window: int):
     """Bit-identical jnp lowering of ``fused_probe`` (the differential
-    oracle the kernel is pinned against). Same visit order, same
-    first-slot rule, same padded-lane masking."""
-    qhia, qhib = _halves(q_hi)
-    qloa, qlob = _halves(q_lo)
-    live = q_b >= 0
-    u = jnp.arange(planes.shape[0])[:, None]
-
-    def row_hits(qrow):
-        g = planes[u, :, jnp.clip(qrow, 0, ROWS - 1)]      # (U, C, FEATS)
-        g = jnp.where((qrow >= 0)[..., None], g, 0)
-
-        def f(k):
-            return g[..., k * SROWS:(k + 1) * SROWS]       # (U, C, SROWS)
-
-        eq = ((f(F_ALLOC) == 1) & (f(F_FP) == q_fp[..., None])
-              & (f(F_KHIA) == qhia[..., None]) & (f(F_KHIB) == qhib[..., None])
-              & (f(F_KLOA) == qloa[..., None]) & (f(F_KLOB) == qlob[..., None])
-              & live[..., None])
-        val = f(F_VA) | (f(F_VB) << 16)
-        j = jnp.argmax(eq, axis=-1)
-        return jnp.any(eq, axis=-1), jnp.where(
-            jnp.any(eq, axis=-1),
-            jnp.take_along_axis(val, j[..., None], axis=-1)[..., 0], 0)
-
-    found, val = row_hits(q_b)
-    for qrow in [q_pb] + [jnp.full_like(q_b, nb + s) for s in range(ns)]:
-        ok, v = row_hits(qrow)
-        val = jnp.where(ok & ~found, v, val)
+    oracle the kernel is pinned against): the same candidate rows visited
+    in order, the first matching slot of the first matching row."""
+    khi, klo, val, fp, meta = planes
+    slots = jnp.arange(khi.shape[0], dtype=U32)
+    rows = [b] + ([(b + 1) & (nb - 1)] if window == 2 else [])
+    rows += [jnp.full_like(b, nb + k) for k in range(ns)]
+    found = jnp.zeros(seg.shape, jnp.bool_)
+    value = jnp.zeros(seg.shape, U32)
+    for w, r in enumerate(rows):
+        hit = ((layout.meta_alloc(meta[r, seg])[:, None] >> slots)
+               & U32(1)) == 1                                     # (n, SL)
+        if fp is not None:
+            hit &= (fp[r[:, None], slots, seg[:, None]].astype(I32)
+                    == q_fp[:, None])
+        hit &= ((khi[:, r, seg].T == q_hi[:, None])
+                & (klo[:, r, seg].T == q_lo[:, None]))
+        if w >= len(rows) - ns:                                   # stash row
+            hit &= w - (len(rows) - ns) < active[:, None]
+        ok = jnp.any(hit, axis=1)
+        v = jnp.take_along_axis(val[:, r, seg].T,
+                                jnp.argmax(hit, axis=1)[:, None], axis=1)[:, 0]
+        value = jnp.where(ok & ~found, v, value)
         found = found | ok
-    return found.astype(jnp.int32), val.astype(U32)
+    return found.astype(I32), value
 
 
 # ---------------------------------------------------------------------------
 # fused read — host-facing dispatch
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 6, 7))
-def _fused_search_routed(cfg: DashConfig, mode: str, state: DashState,
-                         keys_hi, keys_lo, words, capacity: int,
-                         interpret: bool):
-    """TPU path: route queries to the segments the batch touches, run the
-    mega-kernel over those segments only, scatter results back.
-    Capacity-overflow lanes fall back to the direct path, mirroring
-    ``_search_batch_routed``. ``interpret=True`` runs the kernel in the
-    Pallas interpreter (the CPU tests); the TPU dispatcher passes False."""
+@functools.partial(jax.jit, static_argnums=(0, 1, 5))
+def _fused_search_kernel(cfg: DashConfig, mode: str, state: DashState,
+                         keys_hi, keys_lo, interpret: bool):
+    """TPU path: hash and locate the batch's queries in XLA (a gather of
+    one directory word each), then ``fused_probe`` reads each query's
+    candidate rows where the planes lie and writes its result in query
+    order. ``interpret=True`` runs the kernel in the Pallas interpreter
+    (the CPU tests); the TPU dispatcher passes False."""
     from repro.kernels import ops
     h1 = hashing.hash1(keys_hi, keys_lo)
     h2 = hashing.hash2(keys_hi, keys_lo)
-    fpv = (h2 & U32(0xFF)).astype(jnp.int32)
-    if not cfg.use_fingerprints:
-        fpv = jnp.zeros_like(fpv)
     seg, b = ops.locate_batch(cfg, mode, state, h1)
-    NB = cfg.num_buckets
-    n = keys_hi.shape[0]
-    segments, cid = ops.touched_segments(seg, min(n, cfg.max_segments))
-    lanes, src, keep = ops.route_lanes(
-        cid, (fpv, b.astype(jnp.int32), keys_hi, keys_lo),
-        segments.shape[0], capacity, (-1, -1, 0, 0))
-    q_fp, q_b, q_hi, q_lo = lanes
-    q_pb = jnp.where(q_b >= 0, (q_b + 1) & (NB - 1), -1)
-    f, v = fused_probe(fused_plane_views(cfg, state, segments), q_fp, q_b,
-                       q_pb, q_hi, q_lo, nb=NB, ns=cfg.num_stash,
-                       interpret=interpret)
-    flatf, flatv = f.reshape(-1) != 0, v.reshape(-1)
-    srcf = src.reshape(-1)
-    ok = jnp.clip(srcf, 0)
-    found = jnp.zeros((n,), jnp.bool_).at[ok].max(jnp.where(srcf >= 0, flatf, False))
-    val = jnp.zeros((n,), U32).at[ok].max(jnp.where(srcf >= 0, flatv, U32(0)))
-    if capacity >= n:
-        return found, val           # no lane can overflow: keep is all-True
+    queries = (seg, b, keys_hi, keys_lo, hashing.fingerprint(h2).astype(I32),
+               state.stash_active[seg])
+    views = stored_views(state, cfg.use_fingerprints)
+    parts = [fused_probe(views, *(q[i:i + MAX_QUERIES] for q in queries),
+                         nb=cfg.num_buckets, ns=cfg.num_stash,
+                         window=cfg.probe_window, interpret=interpret)
+             for i in range(0, keys_hi.shape[0], MAX_QUERIES)]
+    return (jnp.concatenate([f for f, _ in parts]) != 0,
+            jnp.concatenate([v for _, v in parts]))
 
-    def fallback(_):
-        return _fused_search_direct(cfg, mode, state, keys_hi, keys_lo, words)
 
-    def none(_):
-        return jnp.zeros_like(found), jnp.zeros_like(val)
-
-    f2, v2 = jax.lax.cond(jnp.any(~keep), fallback, none, None)
-    return jnp.where(keep, found, f2), jnp.where(keep, val, v2)
+def fused_read_path(cfg: DashConfig) -> str:
+    """Which program ``fused_search`` runs for ``cfg`` on this host:
+    ``"kernel"`` (the per-query Pallas kernel, TPU hosts and configs in
+    its span) or ``"direct"`` (the jnp lowering)."""
+    if jax.default_backend() == "tpu" and fused_kernel_eligible(cfg):
+        return "kernel"
+    return "direct"
 
 
 def fused_search(cfg: DashConfig, mode: str, state: DashState,
-                 keys_hi, keys_lo, words=None, capacity: int | None = None):
+                 keys_hi, keys_lo, words=None):
     """Single-dispatch batched lookup. Returns (found, values), bit-identical
     to ``engine.search_batch(batching="vmap")``.
 
-    Non-TPU hosts always take the direct-addressed lowering (one gather +
-    one dense compare — no routing, which is the whole point at small
-    batches). TPU hosts take the routed mega-kernel when the config is in
-    its span, the direct lowering otherwise. ``capacity`` is the lanes per
-    touched segment; the default (the padded batch) can never overflow."""
-    n = keys_hi.shape[0]
-    if words is None:
-        words = jnp.zeros((n, cfg.key_heap_words), U32)
-    if jax.default_backend() == "tpu" and fused_kernel_eligible(cfg):
-        if capacity is None:
-            capacity = max(BQ, 1 << (max(n - 1, 1)).bit_length())
-        return _fused_search_routed(cfg, mode, state, keys_hi, keys_lo,
-                                    words, capacity, False)
+    TPU hosts take the per-query kernel when the config is in its span;
+    other hosts and configs take the direct-addressed lowering (one gather
+    + one dense compare). Only pointer mode reads ``words``."""
+    if fused_read_path(cfg) == "kernel":
+        return _fused_search_kernel(cfg, mode, state, keys_hi, keys_lo, False)
+    if words is None and cfg.pointer_mode:
+        words = jnp.zeros((keys_hi.shape[0], cfg.key_heap_words), U32)
     return _fused_search_direct(cfg, mode, state, keys_hi, keys_lo, words)
 
 

@@ -271,6 +271,9 @@ class FrontendBase:
         out["degraded_events"] = self.degraded_events
         out["readonly_events"] = self.readonly_events
         out["unflushed_publishes"] = self.unflushed_publishes
+        for path in ("kernel", "direct"):       # DashTable.count_read_batch
+            c = self.obs.registry.get(f"reads.fused_{path}_batches")
+            out[f"fused_{path}_batches"] = 0 if c is None else c.value
         table = getattr(self, "table", None)
         if table is not None:
             report = getattr(table, "lost_report", [])
@@ -521,6 +524,7 @@ class DashFrontend(FrontendBase):
                 found, vals = dash_engine.search_batch(
                     self.cfg, self.mode, snap.state, hi, lo,
                     batching=self.read_batching)
+                self.table.count_read_batch(self.read_batching)
             with tr.span("read.wait", "serving"):
                 found = np.asarray(found).copy()
                 vals = np.asarray(vals).copy()
@@ -541,6 +545,7 @@ class DashFrontend(FrontendBase):
                         f2, v2 = dash_engine.search_batch(
                             self.cfg, self.mode, self.table.state, hi, lo,
                             batching=self.read_batching)
+                        self.table.count_read_batch(self.read_batching)
                         found[changed] = np.asarray(f2)[changed]
                         vals[changed] = np.asarray(v2)[changed]
         self._finish_reads(ops, found, vals, n_changed)

@@ -197,6 +197,35 @@ def test_frontend_ticks_identical_fused_on_off(rng):
     assert fe_on.retried_reads == fe_off.retried_reads
 
 
+def test_frontend_counts_fused_read_batches(monkeypatch):
+    """Every fused read dispatch of the frontend — the tick's read and the
+    verify pass's retry — is counted once in the table's obs registry by
+    the program it ran (the direct lowering on a host without a TPU), and
+    ``stats()`` mirrors both counters."""
+    calls = []
+    search = dash_engine.search_batch
+
+    def spy(*args, **kw):
+        calls.append(kw.get("batching"))
+        return search(*args, **kw)
+
+    monkeypatch.setattr(dash_engine, "search_batch", spy)
+    _, ops = _mixed_stream(np.random.default_rng(29))
+    t = DashEH(CFG)
+    fe = DashFrontend(t, max_batch=128, queue_depth=1 << 14,
+                      fused_reads=True)
+    for op in ops:
+        assert fe.submit(op)
+    fe.drain()
+    st = fe.stats()
+    n = calls.count("fused")
+    assert st["fused_direct_batches"] == n > 0
+    assert st["fused_kernel_batches"] == 0
+    assert fe.retried_reads > 0          # the retry dispatches were counted
+    t.search(np.asarray([op.key for op in ops[:64]], np.uint64))
+    assert fe.stats()["fused_direct_batches"] == calls.count("fused") == n + 1
+
+
 def test_frontend_rmw_and_delete(rng):
     t = DashEH(CFG)
     fe = DashFrontend(t, max_batch=64, queue_depth=4096)

@@ -8,10 +8,11 @@ activation) and ``fused_search`` == the per-key vmap path (found + values),
 across the feature-flag matrix (balanced / displacement / fingerprints /
 overflow-metadata / stash ablations), LH addressing, pointer mode, padding
 (valid) masks, in-batch duplicate keys, stash overflow and NEED_SPLIT
-pressure. The Pallas mega-kernel and its jnp lowering are differentially
-checked against each other and the vmap reference too.
+pressure. The per-query Pallas read kernel and its jnp lowering are
+differentially checked against each other and the vmap reference too.
 """
 import dataclasses as dc
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -149,101 +150,95 @@ def test_fused_search_pointer_mode():
         assert (np.asarray(v_v) == np.asarray(v_f)).all()
 
 
-def test_fused_kernel_matches_lowering_and_vmap():
-    """The Pallas mega-kernel (interpret mode on CPU) and its jnp lowering
-    must agree lane-for-lane, and both must agree with the per-key vmap
-    reference on every kept (routed) lane."""
-    cfg = DashConfig(max_segments=8, dir_depth_max=6, init_depth=1)
-    rng = np.random.default_rng(0xCAFE)
-    state = layout.make_state(cfg, "eh")
-    hi, lo = _keys(rng, 256)
-    vals = jnp.asarray(np.arange(256, dtype=np.uint32) + 1)
-    state, _, _ = engine.insert_batch(cfg, "eh", state, hi, lo, vals,
-                                      batching="scan")
-    # queries: half hits, half misses
-    mh, ml = _keys(np.random.default_rng(7), 128)
-    qhi = jnp.concatenate([hi[:128], mh])
-    qlo = jnp.concatenate([lo[:128], ml])
+#: cases of the per-query read kernel: (config, mode, keys inserted)
+KERNEL_CASES = {
+    "eh": (CONFIGS["default"], "eh", 900),
+    "no_fp": (CONFIGS["no_fp"], "eh", 900),
+    "lh": (DashConfig(max_segments=32, num_stash=4, lh_base_log2=2), "lh",
+           900),
+    "stash": (CONFIGS["small_buckets"], "eh", 300),
+    "hot": (CONFIGS["default"], "eh", 900),
+}
 
-    from repro.kernels import ops
-    h1 = hashing.hash1(qhi, qlo)
-    h2 = hashing.hash2(qhi, qlo)
-    fpv = (h2 & jnp.uint32(0xFF)).astype(jnp.int32)
-    seg, b = ops.locate_batch(cfg, "eh", state, h1)
-    NB = cfg.num_buckets
-    capacity = 256                      # BQ-aligned
-    lanes, src, keep = ops.route_lanes(
-        seg, (fpv, b.astype(jnp.int32), qhi, qlo, seg >= 0),
-        cfg.max_segments, capacity, (0, -1, 0, 0, False))
-    q_fp, q_b, q_hi, q_lo, q_valid = lanes
-    q_b = jnp.where(q_valid, q_b, -1)
-    q_pb = jnp.where(q_valid, (q_b + 1) & (NB - 1), -1)
-    q_fp = jnp.where(q_valid, q_fp, -1)
-    planes = fused.fused_plane_views(
-        cfg, state, jnp.arange(cfg.max_segments, dtype=jnp.int32))
-    f_k, v_k = fused.fused_probe(planes, q_fp, q_b, q_pb, q_hi, q_lo,
-                                 nb=NB, ns=cfg.num_stash, interpret=True)
-    f_j, v_j = fused.fused_probe_jnp(planes, q_fp, q_b, q_pb, q_hi, q_lo,
-                                     nb=NB, ns=cfg.num_stash)
+
+@functools.lru_cache(maxsize=None)
+def _kernel_case(case):
+    """(cfg, mode, state, q_hi, q_lo) for one case of the read kernel."""
+    cfg, mode, n = KERNEL_CASES[case]
+    rng = np.random.default_rng(0x5EA)
+    hi, lo = _keys(rng, n + 64)
+    state, _, _ = engine.insert_batch(
+        cfg, mode, layout.make_state(cfg, mode), hi[:n], lo[:n],
+        jnp.arange(1, n + 1, dtype=jnp.uint32), batching="scan")
+    zeros = jnp.zeros(8, jnp.uint32)
+    if case == "stash":
+        # every inserted key, so the records in stash rows are read too;
+        # 300 queries: the last output row is partly padding
+        stash_alloc = np.asarray(state.meta)[:, cfg.num_buckets:] & 0x3FFF
+        assert stash_alloc.any()
+        return cfg, mode, state, hi[:n], lo[:n]
+    if case == "hot":
+        # eight hot keys 24 times each, misses, zero-key padding lanes
+        pick = np.concatenate([np.repeat(np.arange(8), 24),
+                               np.arange(n, n + 56)])
+        return (cfg, mode, state, jnp.concatenate([hi[pick], zeros]),
+                jnp.concatenate([lo[pick], zeros]))
+    # hits, misses (keys n.. were never inserted), zero-key padding lanes
+    pick = np.arange(n - 192, n + 56)
+    return (cfg, mode, state, jnp.concatenate([hi[pick], zeros]),
+            jnp.concatenate([lo[pick], zeros]))
+
+
+def _assert_matches_vmap(case, found, vals):
+    cfg, mode, state, q_hi, q_lo = _kernel_case(case)
+    f_v, v_v = engine.search_batch(cfg, mode, state, q_hi, q_lo,
+                                   batching="vmap")
+    assert np.asarray(f_v).any()
+    assert case == "stash" or not np.asarray(f_v).all()
+    assert (np.asarray(found) == np.asarray(f_v)).all()
+    assert (np.asarray(vals) == np.asarray(v_v)).all()
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_fused_kernel_matches_lowering_and_vmap(case):
+    """The per-query Pallas kernel (interpret mode on CPU) and its jnp
+    lowering agree lane for lane on the table's stored-layout views, and
+    both agree with the per-key vmap reference."""
+    cfg, mode, state, q_hi, q_lo = _kernel_case(case)
+    seg, b = engine.locate(cfg, mode, state, hashing.hash1(q_hi, q_lo))
+    args = (fused.stored_views(state, cfg.use_fingerprints), seg, b, q_hi,
+            q_lo, hashing.fingerprint(hashing.hash2(q_hi, q_lo)).astype(
+                jnp.int32), state.stash_active[seg])
+    kw = dict(nb=cfg.num_buckets, ns=cfg.num_stash, window=cfg.probe_window)
+    f_k, v_k = fused.fused_probe(*args, **kw, interpret=True)
+    f_j, v_j = fused.fused_probe_jnp(*args, **kw)
     assert (np.asarray(f_k) == np.asarray(f_j)).all()
     assert (np.asarray(v_k) == np.asarray(v_j)).all()
-    # scatter back and compare with vmap on kept lanes
-    f_ref, v_ref = engine.search_batch(cfg, "eh", state, qhi, qlo,
-                                       batching="vmap")
-    flatf, flatv = np.asarray(f_j).reshape(-1), np.asarray(v_j).reshape(-1)
-    srcf = np.asarray(src).reshape(-1)
-    keep_np = np.asarray(keep)
-    got_f = np.zeros(qhi.shape[0], bool)
-    got_v = np.zeros(qhi.shape[0], np.uint32)
-    m = srcf >= 0
-    got_f[srcf[m]] = flatf[m] != 0
-    got_v[srcf[m]] = flatv[m]
-    assert (got_f[keep_np] == np.asarray(f_ref)[keep_np]).all()
-    assert (got_v[keep_np] == np.asarray(v_ref)[keep_np]).all()
+    _assert_matches_vmap(case, np.asarray(f_j) != 0, v_j)
 
 
-def _routed_case(case):
-    """(cfg, state, hi, lo, capacity) for one ``_fused_search_routed`` case."""
-    from repro.kernels import ops
-    cfg = CONFIGS["no_fp" if case == "no_fp" else "default"]
-    rng = np.random.default_rng(0x5EA)
-    hi, lo = _keys(rng, 1024)
-    state, st, _ = engine.insert_batch(
-        cfg, "eh", layout.make_state(cfg, "eh"), hi[:768], lo[:768],
-        jnp.arange(1, 769, dtype=jnp.uint32), batching="scan")
-    # hits, then misses (keys 768.. were never inserted)
-    q_hi, q_lo = hi[256:1024], lo[256:1024]
-    seg, _ = ops.locate_batch(cfg, "eh", state,
-                              hashing.hash1(q_hi, q_lo))
-    seg = np.asarray(seg)
-    if case == "padded":
-        # few touched segments, each repeated, in a batch wider than the
-        # segment count: touched_segments pads its rows with segment 0
-        one = np.flatnonzero(seg == seg.max())
-        pick = np.concatenate([one[one < 512][:16], one[one >= 512][:8]])
-        pick = np.concatenate([pick, pick[:8], np.arange(8)])
-        q_hi, q_lo = q_hi[pick], q_lo[pick]
-        assert np.unique(seg[pick]).size < min(pick.size, cfg.max_segments)
-        return cfg, state, q_hi, q_lo, 128
-    if case == "overflow":
-        capacity = 128
-        assert np.bincount(seg).max() > capacity    # the cond fallback runs
-        return cfg, state, q_hi, q_lo, capacity
-    return cfg, state, q_hi, q_lo, 1024
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_fused_search_kernel_matches_vmap(case):
+    """The TPU read program — hash, locate, the per-query kernel in the
+    Pallas interpreter — returns exactly what the per-key vmap path
+    returns, in query order."""
+    cfg, mode, state, q_hi, q_lo = _kernel_case(case)
+    assert fused.fused_kernel_eligible(cfg)
+    f_r, v_r = fused._fused_search_kernel(cfg, mode, state, q_hi, q_lo, True)
+    _assert_matches_vmap(case, f_r, v_r)
 
 
-@pytest.mark.parametrize("case", ["no_fp", "overflow", "padded"])
-def test_fused_search_routed_matches_vmap(case):
-    """The TPU read program (touched-segment routing, -1 padding lanes,
-    capacity-overflow fallback) with the kernel in the Pallas interpreter
-    returns exactly what the per-key vmap path returns."""
-    cfg, state, q_hi, q_lo, capacity = _routed_case(case)
-    words = jnp.zeros((q_hi.shape[0], cfg.key_heap_words), jnp.uint32)
-    f_r, v_r = fused._fused_search_routed(cfg, "eh", state, q_hi, q_lo,
-                                          words, capacity, True)
-    f_v, v_v = engine.search_batch(cfg, "eh", state, q_hi, q_lo,
+def test_fused_search_kernel_splits_large_batches(monkeypatch):
+    """A batch over ``MAX_QUERIES`` runs as several kernel calls (their
+    prefetched scalars must fit the scalar memory); answers stay in query
+    order and equal the vmap path's."""
+    monkeypatch.setattr(fused, "MAX_QUERIES", 128)
+    cfg, mode, state, q_hi, q_lo = _kernel_case("stash")
+    q_hi, q_lo = q_hi[:290], q_lo[:290]       # a shape no other test traced
+    f_r, v_r = fused._fused_search_kernel(cfg, mode, state, q_hi, q_lo, True)
+    f_v, v_v = engine.search_batch(cfg, mode, state, q_hi, q_lo,
                                    batching="vmap")
-    assert np.asarray(f_v).any() and not np.asarray(f_v).all()
+    assert np.asarray(f_v).any()
     assert (np.asarray(f_r) == np.asarray(f_v)).all()
     assert (np.asarray(v_r) == np.asarray(v_v)).all()
 

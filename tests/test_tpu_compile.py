@@ -2,7 +2,7 @@
 
 The TPU compiler is installed with jaxlib, and it compiles for a chip that
 is described rather than attached. These tests lower every Pallas kernel of
-the read path, and the two routed search programs at the table size
+the read path, and the two search programs at the table size
 ``chip_smoke.py`` serves, with ``interpret=False``: they catch block shapes
 the Mosaic compiler refuses and programs that do not fit one chip's 16 GB,
 which the interpret-mode tests cannot see. Nothing runs, so they say nothing
@@ -12,7 +12,9 @@ The topology is described inside a module-scoped fixture (never at import):
 only one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,13 +83,16 @@ def test_fingerprint_probe_compiles(chip):
 
 
 def test_fused_probe_compiles(chip):
-    U, C = 256, 2 * fused.BQ
+    cfg, n = SMOKE_CFG, 256
     i32, u32 = jnp.int32, jnp.uint32
-    q = _spec(chip, (U, C), i32)
+    planes = jax.eval_shape(lambda st: fused.stored_views(st, True),
+                            _state(chip, cfg))
+    planes = jax.tree.map(lambda x: _spec(chip, x.shape, x.dtype), planes)
+    q = _spec(chip, (n,), i32)
     _check(fused.fused_probe.lower(
-        _spec(chip, (U, fused.FEATS, fused.ROWS), i32), q, q, q,
-        _spec(chip, (U, C), u32), _spec(chip, (U, C), u32),
-        nb=64, ns=2, interpret=False).compile())
+        planes, q, q, _spec(chip, (n,), u32), _spec(chip, (n,), u32), q, q,
+        nb=cfg.num_buckets, ns=cfg.num_stash, window=cfg.probe_window,
+        interpret=False).compile())
 
 
 def test_bulk_hash_compiles(chip):
@@ -107,11 +112,44 @@ def test_search_batch_routed_compiles(chip, n, capacity):
         capacity).compile())
 
 
-@pytest.mark.parametrize("n,capacity", [(1024, 128), (256, 256)])
-def test_fused_search_routed_compiles(chip, n, capacity):
-    """Small read batches: the fused mega-kernel program over the segments
-    the batch touches."""
+@pytest.mark.parametrize("n", [1024, 256])
+def test_fused_search_routed_compiles(chip, n):
+    """Small read batches: the fused read program, one kernel step per
+    query."""
     cfg = SMOKE_CFG
-    _check(fused._fused_search_routed.lower(
-        cfg, "eh", _state(chip, cfg), *_queries(chip, cfg, n),
-        capacity, False).compile())
+    _check(fused._fused_search_kernel.lower(
+        cfg, "eh", _state(chip, cfg), *_queries(chip, cfg, n)[:2],
+        False).compile())
+
+
+#: an HLO instruction: ``%name = <result shape> <opcode>(<operands>)``
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\S+?)(?:\{[^}]*\})? ([a-z][\w-]*)\(([^)]*)\)")
+
+
+def test_fused_read_program_reads_planes_in_place(chip):
+    """The fused read program at the benchmark cell's table (2**14
+    segments of 64+2 buckets, 14 slots, batch 256) reads the planes where
+    they lie: no instruction but a parameter or a bitcast has a shape with
+    the 16,384-segment axis in a plane of two or more dimensions (no
+    whole-plane relayout or staging copy), and no scatter takes more
+    indices or updates than the batch holds (no scatter-back of routed
+    lanes)."""
+    cfg = SMOKE_CFG
+    n, segs = 256, cfg.max_segments
+    text = fused._fused_search_kernel.lower(
+        cfg, "eh", _state(chip, cfg), *_queries(chip, cfg, n)[:2],
+        False).compile().as_text()
+    instrs = [m.groups() for m in map(_INSTR.match, text.splitlines()) if m]
+    dims = {name: [int(d) for d in re.findall(r"\d+", shape.split("[")[-1])]
+            for name, shape, _, _ in instrs}
+    planes = [name for name, _, op, _ in instrs
+              if op not in ("parameter", "bitcast")
+              and len(dims[name]) >= 2 and segs in dims[name]]
+    scatters = [name for name, _, op, args in instrs if op == "scatter"
+                and max(math.prod(dims[a]) for a in
+                        re.findall(r"%(\S+?)[,)]", args + ")")[1:]) > n]
+    assert "tpu_custom_call" in text
+    assert len(dims) > 50            # the instructions were read
+    assert not planes, planes
+    assert not scatters, scatters
